@@ -56,7 +56,7 @@ native: ## Pre-build the C accelerators (otherwise built lazily in background)
 	$(PYTHON) -c "from karpenter_tpu.native import load_kquantity; \
 		assert load_kquantity() is not None, 'native build failed'; print('native ok')"
 
-bench: ## Headline benchmark (runs on the real TPU when present)
+bench: ## Headline benchmark (needs the TPU; JAX_PLATFORMS=cpu for a CPU trajectory run)
 	$(PYTHON) bench.py
 
 bench-solver: ## Direct vs coalesced solver-service p50/p99 (10k pods x 50 types); appends a BENCHMARKS row + publishes to BASELINE.json

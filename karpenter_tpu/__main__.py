@@ -210,13 +210,13 @@ def parse_args(argv=None) -> argparse.Namespace:
         "--compile-cache-dir",
         default=None,
         metavar="DIR",
-        help="persistent jit compile cache directory (the first-class "
-        "form of the KARPENTER_COMPILE_CACHE env var, matching the "
+        help="persistent jit compile cache directory (matching the "
         "sidecar's flag): restarted processes reload compiled solver "
         "programs from disk instead of recompiling — with "
         "--prewarm-compile the boot warm-up becomes a disk read "
-        "(docs/solver-service.md 'Compile pre-warm'). The flag wins "
-        "over the env var when both are set",
+        "(docs/solver-service.md 'Compile pre-warm'). "
+        "JAX_COMPILATION_CACHE_DIR wins over it; with neither, the "
+        "cache is <checkout>/.jax_compile_cache",
     )
     parser.add_argument(
         "--event-driven",
@@ -799,32 +799,22 @@ def _run_loop(args, runtime, elector) -> None:
 
 
 def _setup_backend(args) -> None:
-    """Compile cache + backend probe, before the first jit.
+    """Compile cache + backend, before the first jit.
 
     Standalone mode compiles the decision kernel (and, without
-    --solver-uri, the bin-pack) in-process: honor the same persistent
-    compile cache the sidecar offers, so control-plane restarts skip
-    recompiles too. --compile-cache-dir is the first-class flag
-    (matching the sidecar's), with KARPENTER_COMPILE_CACHE as the
-    env fallback for existing deployments. And the batched HPA decision
-    kernel ALWAYS runs in-process (only the bin-pack is optionally
-    routed to a sidecar), so an unreachable TPU must degrade to CPU
-    decisions unconditionally — not freeze the control plane at its
-    first jit (utils/backend.py rationale)."""
-    import os as _os
+    --solver-uri, the bin-pack) in-process, so it arms the same
+    persistent compile cache as the sidecar (utils/backend.py:
+    JAX_COMPILATION_CACHE_DIR, else --compile-cache-dir, else the
+    in-checkout default). With JAX_PLATFORMS unset the process needs the
+    TPU and fails without one; the split deployment starts this process
+    with JAX_PLATFORMS=cpu, because the sidecar owns the chip."""
+    from karpenter_tpu.utils.backend import arm_compile_cache, require_backend
 
-    from karpenter_tpu.utils.backend import (
-        configure_compile_cache,
-        ensure_usable_backend,
-    )
-
-    configure_compile_cache(
-        args.compile_cache_dir
-        or _os.environ.get("KARPENTER_COMPILE_CACHE", "")
-    )
-    note = ensure_usable_backend()
-    if note:
-        print(f"decision backend: {note}", file=sys.stderr)
+    arm_compile_cache(args.compile_cache_dir)
+    try:
+        require_backend()
+    except RuntimeError as error:
+        sys.exit(f"karpenter-tpu: {error}")
 
 
 def main(argv=None) -> int:

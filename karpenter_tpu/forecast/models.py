@@ -25,9 +25,11 @@ parity across XLA and numpy is only achievable by construction, so the
 kernel obeys two rules mirrored exactly on the host:
 
   * every multiply-accumulate is written in single-mul form
-    (`a * b + c`, the lerp form `c + a*(x - c)` for smoothing updates):
-    XLA:CPU contracts exactly that shape into one FMA, which the numpy
-    mirror reproduces with a float64 round-trip
+    (`a * b + c`, the lerp form `c + a*(x - c)` for smoothing updates)
+    and its product has no other user: XLA:CPU contracts exactly that
+    shape into one FMA for every operand shape (a product shared by
+    several sums is contracted for some shapes and not others), which
+    the numpy mirror reproduces with a float64 round-trip
     (`f32(f64(a)*f64(b) + f64(c))` — the product is exact in f64, so
     the round-trip equals the fused single rounding);
   * every reduction over time is a SEQUENTIAL scan (lax.scan on device,
@@ -155,13 +157,16 @@ def _linear_sums(values, valid, times, weights):
         sw, st, sv, stt, stv = carry
         x, v, t, w0 = xt
         w = jnp.where(v, w0, _ZERO)
-        wt = w * t
+        # every product feeding an add has that add as its ONLY user:
+        # XLA:CPU contracts a shared product (one `w * t` feeding three
+        # sums) into an FMA for some shapes and not others, while a
+        # single-use `a * b + c` is contracted for every shape
         return (
             sw + w,
-            wt + st,
+            w * t + st,
             w * x + sv,
-            wt * t + stt,
-            wt * x + stv,
+            w * (t * t) + stt,
+            w * (t * x) + stv,
         ), None
 
     z = jnp.zeros(S, jnp.float32)
@@ -400,12 +405,11 @@ def _np_linear_fit(values, valid, times, weights):
     for t in range(T):
         x, v, tt, w0 = values[:, t], valid[:, t], times[:, t], weights[:, t]
         w = np.where(v, w0, _ZERO).astype(np.float32)
-        wt = w * tt
         sw = sw + w
         st = _fma(w, tt, st)
         sv = _fma(w, x, sv)
-        stt = _fma(wt, tt, stt)
-        stv = _fma(wt, x, stv)
+        stt = _fma(w, tt * tt, stt)
+        stv = _fma(w, tt * x, stv)
     den = _fma(sw, stt, -(st * st))
     num = _fma(sw, stv, -(st * sv))
     ok = den > 0

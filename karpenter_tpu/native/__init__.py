@@ -8,11 +8,14 @@ pod watch-event re-encode). Every native entry point has a Python oracle
 degrades to the oracle silently.
 
 Build: compiled once into native/_build/ with the running interpreter's
-sysconfig flags; rebuilt when the .c source is newer than the .so.
+sysconfig flags. The object's file name carries a hash of its .c source,
+so only a binary built from the source on disk is ever loaded: a copied
+or stale .so, whatever its mtime, is simply never looked up.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sysconfig
@@ -30,9 +33,8 @@ def _compile(src: str, out: str) -> bool:
     include = sysconfig.get_path("include")
     cc = sysconfig.get_config_var("CC") or "cc"
     # compile to a private temp path, then atomically publish: a concurrent
-    # or killed compile must never leave a torn .so at the final path (it
-    # would carry a fresh mtime and silently disable the accelerator
-    # forever after)
+    # or killed compile must never leave a torn .so at the final path
+    # (its name would match the source and it would never be rebuilt)
     tmp = f"{out}.{os.getpid()}.tmp"
     cmd = [
         *cc.split(),
@@ -61,18 +63,22 @@ def _compile(src: str, out: str) -> bool:
                 pass
 
 
-def _build_and_load(src_name: str, so_name: str, bind):
-    """The shared build-on-demand scaffold: staleness check, compile,
+def built_path(src_name: str, stem: str) -> str:
+    """native/_build/<stem>-<sha256 of the source, 16 hex>.so."""
+    with open(os.path.join(_HERE, src_name), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"{stem}-{digest}.so")
+
+
+def _build_and_load(src_name: str, stem: str, bind):
+    """The shared build-on-demand scaffold: source-keyed lookup, compile,
     bind. Returns the bound handle or None; callers own the caching."""
-    src = os.path.join(_HERE, src_name)
-    so = os.path.join(_BUILD_DIR, so_name)
     try:
+        so = built_path(src_name, stem)
         os.makedirs(_BUILD_DIR, exist_ok=True)
-        stale = (
-            not os.path.exists(so)
-            or os.path.getmtime(so) < os.path.getmtime(src)
-        )
-        if stale and not _compile(src, so):
+        if not os.path.exists(so) and not _compile(
+            os.path.join(_HERE, src_name), so
+        ):
             return None
         return bind(so)
     except Exception:
@@ -109,7 +115,7 @@ def load_kquantity() -> Optional[object]:
             return _kquantity
         _tried = True
         _kquantity = _build_and_load(
-            "quantity.c", "_kquantity.so", _bind_extension
+            "quantity.c", "_kquantity", _bind_extension
         )
         return _kquantity
 
@@ -130,7 +136,7 @@ def load_kbinpack() -> Optional[object]:
             return _kbinpack
         _kbinpack_tried = True
         _kbinpack = _build_and_load(
-            "binpack_kernel.c", "_kbinpack.so", _bind_ctypes
+            "binpack_kernel.c", "_kbinpack", _bind_ctypes
         )
         return _kbinpack
 

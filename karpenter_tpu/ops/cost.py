@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from karpenter_tpu.ops import ieee
 from karpenter_tpu.ops.decision import _I32_SAFE_MAX, _I32_SAFE_MIN
 
 # Candidate ladder width: each row scores replica counts
@@ -110,7 +111,7 @@ def cost_decide(inputs: CostInputs) -> CostOutputs:
         & (inputs.max_hourly_cost > 0)
     )
     safe_unit = jnp.where(inputs.unit_cost > 0, inputs.unit_cost, _ONE)
-    cap = jnp.floor(inputs.max_hourly_cost / safe_unit)
+    cap = jnp.floor(ieee.divide(inputs.max_hourly_cost, safe_unit))
     hi = jnp.where(cap_on, jnp.minimum(max_f, jnp.maximum(cap, min_f)), max_f)
     cand = jnp.clip(
         base[:, None] + offsets[None, :], min_f[:, None], hi[:, None]
@@ -121,7 +122,9 @@ def cost_decide(inputs: CostInputs) -> CostOutputs:
     demand_hi = inputs.demand_mu + inputs.demand_sigma  # [N, M]
     capacity = cand[:, :, None] * inputs.slo_target[:, None, :]  # [N, K, M]
     denom = jnp.maximum(demand_hi, _EPS)[:, None, :]
-    short = jnp.clip((demand_hi[:, None, :] - capacity) / denom, _ZERO, _ONE)
+    short = jnp.clip(
+        ieee.divide(demand_hi[:, None, :] - capacity, denom), _ZERO, _ONE
+    )
     short = jnp.where(inputs.demand_valid[:, None, :], short, _ZERO)
     risk = jnp.max(short, axis=2)  # [N, K]
 
@@ -140,7 +143,9 @@ def cost_decide(inputs: CostInputs) -> CostOutputs:
     # warm-pool sizing signal (docs/cost.md "Warm pools"): how many
     # replicas the one-sigma demand needs BEYOND the chosen count —
     # pre-provisioned headroom sized by forecast risk
-    needed = jnp.ceil(demand_hi / jnp.maximum(inputs.slo_target, _EPS))
+    needed = jnp.ceil(
+        ieee.divide(demand_hi, jnp.maximum(inputs.slo_target, _EPS))
+    )
     needed = jnp.where(inputs.demand_valid, needed, _ZERO)
     headroom = jnp.maximum(jnp.max(needed, axis=1) - chosen, _ZERO)
 

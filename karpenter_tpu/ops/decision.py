@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from karpenter_tpu.ops import ieee
+
 # target types (order matters: encoded into int arrays)
 TYPE_VALUE = 0
 TYPE_AVERAGE_VALUE = 1
@@ -132,7 +134,7 @@ def _recommendations(
     # (algorithms/proportional.py) — float division by zero never reaches XLA
     safe_target = jnp.where(inputs.target_value != 0, inputs.target_value, 1.0)
     ratio = jnp.where(
-        inputs.target_value != 0, values / safe_target, 0.0
+        inputs.target_value != 0, ieee.divide(values, safe_target), 0.0
     )
     status = inputs.status_replicas[:, None].astype(jnp.float32)
     proportional = status * ratio
@@ -225,7 +227,9 @@ def decide(inputs: DecisionInputs) -> DecisionOutputs:
         base = jnp.maximum(spec[:, None], 1.0)
         budget = jnp.where(
             ptype == POLICY_TYPE_PERCENT,
-            _ceil_guarded(base * pvalue.astype(jnp.float32) / 100.0),
+            _ceil_guarded(
+                ieee.divide(base * pvalue.astype(jnp.float32), 100.0)
+            ),
             pvalue.astype(jnp.float32),
         )
         spent = inputs.has_last_scale[:, None] & (

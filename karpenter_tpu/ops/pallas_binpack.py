@@ -383,8 +383,16 @@ def binpack_pallas(
 
 
 def default_interpret() -> bool:
-    """Compiled Mosaic path on TPU; interpreter elsewhere (CPU tests)."""
-    return jax.default_backend() != "tpu"
+    """Compiled Mosaic on the TPU, the interpreter on the CPU (tests).
+    Any other platform raises: interpreting there would pass for the
+    compiled kernel while running orders of magnitude slower."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"no Pallas binpack mode for platform {platform!r}: the "
+            "kernel compiles for 'tpu' and interprets on 'cpu' only"
+        )
+    return platform == "cpu"
 
 
 def pallas_available() -> bool:

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from karpenter_tpu.ops import ieee
 from karpenter_tpu.ops.cost import CANDIDATES, _EPS, _ONE, _ZERO, _fma
 from karpenter_tpu.ops.decision import _I32_SAFE_MAX, _I32_SAFE_MIN
 
@@ -178,7 +179,7 @@ def poolgroup_decide(
         & (inputs.max_hourly_cost > 0)
     )
     safe_unit = jnp.where(inputs.unit_cost > 0, inputs.unit_cost, _ONE)
-    cap = jnp.floor(inputs.max_hourly_cost / safe_unit)
+    cap = jnp.floor(ieee.divide(inputs.max_hourly_cost, safe_unit))
     hi = jnp.where(
         cap_on, jnp.minimum(max_f, jnp.maximum(cap, min_f)), max_f
     )
@@ -192,7 +193,7 @@ def poolgroup_decide(
     capacity = cand[:, :, :, None] * inputs.slo_target[:, :, None, :]
     denom = jnp.maximum(demand_hi, _EPS)[:, :, None, :]
     short = jnp.clip(
-        (demand_hi[:, :, None, :] - capacity) / denom, _ZERO, _ONE
+        ieee.divide(demand_hi[:, :, None, :] - capacity, denom), _ZERO, _ONE
     )
     short = jnp.where(inputs.demand_valid[:, :, None, :], short, _ZERO)
     risk = jnp.max(short, axis=3)  # [G, P, K]
@@ -247,7 +248,9 @@ def poolgroup_decide(
     sel_viol = jnp.take_along_axis(viol, selected[:, None], axis=1)[:, 0]
     sel_spend = jnp.take_along_axis(spend, selected[:, None], axis=1)[:, 0]
 
-    needed = jnp.ceil(demand_hi / jnp.maximum(inputs.slo_target, _EPS))
+    needed = jnp.ceil(
+        ieee.divide(demand_hi, jnp.maximum(inputs.slo_target, _EPS))
+    )
     needed = jnp.where(inputs.demand_valid, needed, _ZERO)
     headroom = jnp.maximum(jnp.max(needed, axis=2) - chosen, _ZERO)
 

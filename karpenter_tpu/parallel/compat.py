@@ -1,48 +1,17 @@
-"""Sharding-API compat surface — honest about the pinned JAX.
+"""The sharding names every module of the repo imports, in one place.
 
-Every sharding import the repo takes rides through here, so exactly one
-module knows which JAX era we target (`pyproject.toml` pins
-``jax>=0.4.30``) and what that era actually provides:
-
-  * ``PartitionSpec`` / ``Mesh`` / ``NamedSharding`` — stable under
-    ``jax.sharding`` since 0.4.x. There is NO fallback rung reaching
-    back to ``from jax.interpreters.sharded_jit import PartitionSpec``:
-    that module was deleted from JAX years before the pin (it predates
-    pjit/GSPMD), the import is unreachable on every version the
-    dependency spec admits, and carrying it as a dead ``except
-    ImportError`` arm would only misrepresent what this repo supports.
-  * ``shard_map`` — promoted to ``jax.shard_map`` in newer releases;
-    the pinned floor still spells it ``jax.experimental.shard_map``.
-    Both are the SAME implementation, so the ladder here is a rename
-    shim, not a behavior fork.
-  * ``pjit`` — retained for callers that want explicit in/out shardings
-    on a mesh program; on the pinned JAX ``jax.jit`` + ``NamedSharding``
-    inputs is the equivalent (and preferred) spelling, which is what
-    `mesh.py`/`solver/service.py` use. ``pjit`` is exported so embedders
-    following the SNIPPETS idiom find it in one place.
-
-If a future JAX bump breaks an import below, fix it HERE (and only
-here) — do not grow per-module try/except ladders.
+The repo targets the one installed JAX (pyproject.toml pins it), so these
+are plain imports: if a JAX bump moves one, fix it here and only here.
 """
 
 from __future__ import annotations
 
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # jax >= 0.6 spelling
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # the pinned 0.4.x floor
-    from jax.experimental.shard_map import shard_map
-
-try:
-    from jax.experimental.pjit import pjit
-except ImportError:  # pjit folded into jax.jit
-    from jax import jit as pjit
 
 __all__ = [
     "Mesh",
     "NamedSharding",
     "PartitionSpec",
-    "pjit",
     "shard_map",
 ]

@@ -96,7 +96,7 @@ def initialize_multihost(
     MUST be called before anything initializes the in-process XLA
     backend (jax.devices(), any computation, even jax.default_backend())
     — jax.distributed.initialize refuses afterwards. The sidecar
-    therefore joins BEFORE its backend probe.
+    therefore joins BEFORE it initializes its backend.
 
     Resolution order per parameter: explicit argument, then standard env
     var. With a FULL explicit topology (all three of coordinator /

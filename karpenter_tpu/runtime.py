@@ -187,7 +187,7 @@ class Options:
     # event arrives, so a cold plane's first event pass doesn't eat a
     # first-touch jit compile (hotpath BASELINE: idle p99 533 ms vs
     # p50 30 ms). Skipped per rung when the compile cache already
-    # hits; the persistent cache (KARPENTER_COMPILE_CACHE) turns the
+    # hits; the persistent compile cache turns the
     # remaining cost into a disk read.
     prewarm_compile: bool = False
     # fused steady-state tick (ops/fusedtick.py, docs/solver-service.md
@@ -199,12 +199,12 @@ class Options:
     # production) turns it on. Outputs are property-pinned bitwise
     # equal to the chained path, so this is latency-only.
     fused_tick: bool = False
-    # persistent compile cache directory (--compile-cache-dir): the
-    # first-class promotion of the KARPENTER_COMPILE_CACHE env var,
-    # matching the sidecar's flag of the same name. Set, jit compiles
-    # taking >=1s persist to disk and a restarted process reloads them
-    # instead of recompiling (utils/backend.configure_compile_cache).
-    # None = env var only (the pre-flag wire).
+    # persistent compile cache directory (--compile-cache-dir, the
+    # sidecar's flag of the same name). Set, jit compiles taking >=1s
+    # persist to disk and a restarted process reloads them instead of
+    # recompiling; JAX_COMPILATION_CACHE_DIR wins over it
+    # (utils/backend.arm_compile_cache). None = the runtime arms
+    # nothing (the CLI arms the cache before building it).
     compile_cache_dir: Optional[str] = None
     # joint pool-group allocation (karpenter_tpu/poolgroups,
     # docs/poolgroups.md): PoolGroup CRDs name member autoscalers with
@@ -251,14 +251,13 @@ class KarpenterRuntime:
         self.registry = registry if registry is not None else GaugeRegistry()
 
         # persistent compile cache, armed BEFORE anything can compile
-        # (the cache singleton latches at first compile): the embedded
-        # Options path mirrors __main__'s flag/env resolution so a
-        # runtime built in-process (tests, library use) gets the same
-        # restart-warm compiles as the CLI.
+        # (the cache singleton latches at first compile) when the
+        # embedder names a directory — through the one resolver the CLI
+        # uses, so JAX_COMPILATION_CACHE_DIR still wins
         if options.compile_cache_dir:
-            from karpenter_tpu.utils.backend import configure_compile_cache
+            from karpenter_tpu.utils.backend import arm_compile_cache
 
-            configure_compile_cache(options.compile_cache_dir)
+            arm_compile_cache(options.compile_cache_dir)
 
         self._bind_observability(options)
 

@@ -15,7 +15,6 @@ checkpoint/resume posture (all durable state in the store; SURVEY.md §5).
 from __future__ import annotations
 
 import json
-import os
 from concurrent import futures
 from typing import Optional
 
@@ -199,28 +198,27 @@ def main(argv=None) -> None:
     )
     ap.add_argument(
         "--compile-cache-dir",
-        default=os.environ.get("KARPENTER_COMPILE_CACHE", ""),
-        help="persistent XLA compilation cache directory (also env "
-        "KARPENTER_COMPILE_CACHE): a restarted sidecar reloads compiled "
-        "solver programs instead of paying the 20-40s TPU compile again; "
-        "point it at an emptyDir/PVC in the pod spec. Empty = disabled.",
+        default=None,
+        help="persistent XLA compilation cache directory: a restarted "
+        "sidecar reloads compiled solver programs instead of paying the "
+        "TPU compile again; point it at an emptyDir/PVC in the pod spec. "
+        "JAX_COMPILATION_CACHE_DIR wins over it; with neither, the "
+        "cache is <checkout>/.jax_compile_cache",
     )
     args = ap.parse_args(argv)
 
-    from karpenter_tpu.utils.backend import configure_compile_cache
+    from karpenter_tpu.utils.backend import arm_compile_cache, require_backend
 
-    configure_compile_cache(args.compile_cache_dir)
+    arm_compile_cache(args.compile_cache_dir)
 
-    joined = False
     if args.multihost:
         # the join must precede ANY backend touch (jax.distributed
-        # refuses after XLA initializes), so it runs before the probe.
-        # A configured-but-broken topology raises and kills the process
-        # — correct: N independent solvers would double-solve the fleet.
+        # refuses after XLA initializes). A configured-but-broken
+        # topology raises and kills the process — correct: N independent
+        # solvers would double-solve the fleet.
         from karpenter_tpu.parallel.multihost import initialize_multihost
 
-        joined = initialize_multihost()
-        if not joined:
+        if not initialize_multihost():
             import sys
 
             print(
@@ -228,21 +226,12 @@ def main(argv=None) -> None:
                 file=sys.stderr,
             )
 
-    if not joined:
-        # the sidecar exists to own the TPU, but a hung accelerator
-        # tunnel must degrade to CPU service (logged loudly), not a
-        # frozen gRPC server. A JOINED multihost member never takes this
-        # fallback: contributing CPU devices to a TPU fleet's global
-        # device set (or silently leaving the fleet) corrupts the mesh —
-        # a member whose accelerator is broken should crash and be
-        # rescheduled, not limp
-        from karpenter_tpu.utils.backend import ensure_usable_backend
-
-        note = ensure_usable_backend()
-        if note:
-            import sys
-
-            print(f"sidecar backend: {note}", file=sys.stderr)
+    # the sidecar owns the chip: without one it fails at start instead
+    # of serving from the CPU
+    try:
+        require_backend()
+    except RuntimeError as error:
+        raise SystemExit(f"karpenter-tpu sidecar: {error}") from error
 
     if args.warmup_pods:
         import jax

@@ -278,6 +278,7 @@ class SolverStatistics:
     shard_dispatches: int = 0  # batches answered by the mesh-sharded program
     shard_requests: int = 0  # requests routed onto the mesh at submit
     shard_fallbacks: int = 0  # shard-path failures retried single-device
+    shard_mesh_errors: int = 0  # mesh builds that failed (served unsharded)
     # device-resident fleet state (solver/resident.py)
     resident_hits: int = 0  # dispatches served from resident buffers as-is
     resident_scatters: int = 0  # dispatches served via a changed-row scatter
@@ -291,6 +292,10 @@ class SolverStatistics:
     fsm_probes: int = 0  # device probes granted while degraded
     fsm_short_circuits: int = 0  # batches routed to numpy with no attempt
     watchdog_restarts: int = 0  # hung-worker restarts
+    # provenance: answers handed to callers, keyed "family/kernel" with
+    # kernel the one that actually served ("pallas", "xla", "numpy", or
+    # the requested backend under a device_solver override)
+    served: Dict[str, int] = field(default_factory=dict)
 
 
 @dataclass
@@ -307,6 +312,7 @@ class _Request:
     result: Optional[object] = None
     error: Optional[BaseException] = None
     abandoned: bool = False
+    degraded: bool = False  # answered by numpy after a device failure
     # consolidate() batch marker: requests sharing an id were enqueued
     # atomically and must ride ONE dispatch — _collect keeps draining the
     # queue past max_batch while the head continues the same batch
@@ -339,6 +345,7 @@ class _Request:
                 return False
             self.result = result
             self.error = error
+            self.degraded = degraded
             self.event.set()
             if self.span is not None:
                 self.span.close(
@@ -365,6 +372,10 @@ class SolveFuture:
             )
         if req.error is not None:
             raise req.error
+        family = req.key[0] if isinstance(req.key[0], str) else "solve"
+        self._service._note_served(
+            family, "numpy" if req.degraded else req.backend
+        )
         return req.result
 
 
@@ -438,6 +449,7 @@ class SolverService:
         self._prewarmed: set = set()
         self._stages: Dict[str, collections.deque] = {}
         self._stage_lock = threading.Lock()
+        self._served_lock = threading.Lock()  # stats.served, from callers
         # worker-only state: batch-size EWMA (adaptive window), in-flight
         # dispatches (pipeline), and the gauges mirroring both
         self._load = 0.0
@@ -553,7 +565,7 @@ class SolverService:
         )
         # boot-time pre-warm: rungs compiled {name=<family>} and the
         # wall cost of each family's warm dispatch — near-zero when the
-        # persistent compile cache (KARPENTER_COMPILE_CACHE) served it
+        # persistent compile cache (JAX_COMPILATION_CACHE_DIR) served it
         self._c_prewarm = reg(SUBSYSTEM, PREWARM_COMPILES, kind="counter")
         self._g_prewarm_ms = reg(SUBSYSTEM, PREWARM_MS)
         # device programs per reconcile tick (note_tick): the fused-tick
@@ -796,8 +808,9 @@ class SolverService:
 
         A family already warmed this process lifetime is SKIPPED (the
         compile cache hits; reset_caches re-arms). With the persistent
-        compile cache (KARPENTER_COMPILE_CACHE) the compile itself is a
-        disk read and the per-family prewarm_ms gauge shows it.
+        compile cache (utils/backend.arm_compile_cache) the compile
+        itself is a disk read and the per-family prewarm_ms gauge shows
+        it.
         Failures degrade, never block boot: a family whose warm dispatch
         errors is reported and skipped — the ladder serves real traffic
         from numpy exactly as it would have without the warm-up."""
@@ -891,7 +904,8 @@ class SolverService:
         """The lazily-built dispatch mesh (parallel/mesh.py), or None
         when sharding is unavailable: disabled (shard_threshold <= 0),
         fewer than 2 devices and no explicit shape, or mesh construction
-        failed (logged once; the single-device path serves)."""
+        failed (logged once and counted in stats.shard_mesh_errors; the
+        single-device path serves)."""
         if self._mesh_ready:
             return self._mesh
         with self._mesh_lock:
@@ -921,6 +935,7 @@ class SolverService:
                         # the single-device program IS the right path
                         mesh = build_mesh(n_devices=n, devices=devices)
             except Exception as error:  # noqa: BLE001 — optional fast path
+                self.stats.shard_mesh_errors += 1
                 logger().warning(
                     "sharded dispatch unavailable (%s: %s); staying "
                     "single-device",
@@ -1070,6 +1085,7 @@ class SolverService:
             logger().warning(
                 "solver queue saturated; degrading one request to numpy"
             )
+            self._note_served("solve", "numpy")
             return self._numpy_fallback(inputs, buckets)
         try:
             return future.result(timeout if timeout else None)
@@ -1079,6 +1095,7 @@ class SolverService:
             logger().warning(
                 "solve deadline expired; degrading one request to numpy"
             )
+            self._note_served("solve", "numpy")
             return self._numpy_fallback(inputs, buckets)
 
     def consolidate(
@@ -1799,6 +1816,11 @@ class SolverService:
             forecast=slice_forecast_outputs(out.forecast, 0, n_series),
         )
 
+    def _note_served(self, family: str, kernel: str) -> None:
+        key = f"{family}/{kernel}"
+        with self._served_lock:
+            self.stats.served[key] = self.stats.served.get(key, 0) + 1
+
     def _annotate_provenance(self, backend: str, rung: str) -> None:
         """Provenance slice (observability/provenance.py): stamp the
         backend + degradation rung that actually served onto the
@@ -1832,6 +1854,9 @@ class SolverService:
             # the decide kernel has no numpy mirror: it is served by
             # the in-process jitted program ("device": XLA on whatever
             # backend jax resolved) or across the gRPC split
+            self._note_served(
+                "decide", "grpc" if self.device_solver is not None else "xla"
+            )
             self._annotate_provenance(
                 "grpc" if self.device_solver is not None else "xla",
                 "sidecar" if self.device_solver is not None
@@ -3055,17 +3080,15 @@ class SolverService:
     _donation_ok: Optional[bool] = None
 
     def _donation_supported(self) -> bool:
-        """Donate only where the backend can actually alias donated
-        buffers (TPU/GPU); on CPU donation is a warning-per-executable
-        no-op, so the worker compiles the non-donating family there.
-        Outputs are identical either way — the donation-parity test
-        compiles BOTH families explicitly regardless of backend."""
+        """Donate only on the TPU, which aliases donated buffers; on CPU
+        donation is a warning-per-executable no-op, so the worker
+        compiles the non-donating family there. Outputs are identical
+        either way — the donation-parity test compiles BOTH families
+        explicitly regardless of backend."""
         if SolverService._donation_ok is None:
             import jax
 
-            SolverService._donation_ok = jax.default_backend() in (
-                "tpu", "gpu", "cuda", "rocm"
-            )
+            SolverService._donation_ok = jax.default_backend() == "tpu"
         return SolverService._donation_ok
 
     def _compiled_for(self, cache_key: tuple, donate: bool = False):

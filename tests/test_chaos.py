@@ -1094,12 +1094,15 @@ class TestEventStormChaos:
                 f"callback thread must never run (or wait on) a solve"
             )
             # the event thread owns the passes: wait for it to drain
-            # the storm through the degradation ladder
+            # the storm through the degradation ladder (an empty dirty
+            # set alone can mean a pass is still running: it swaps the
+            # set out before it reconciles, under the pass lock)
             deadline = _t.monotonic() + 30.0
             while _t.monotonic() < deadline:
                 if (
                     runtime.manager.dirty_count() == 0
                     and service.queue_depth() == 0
+                    and not runtime.manager._pass_lock.locked()
                 ):
                     break
                 _t.sleep(0.02)

@@ -517,18 +517,20 @@ class TestFusedRuntimeFixedPoint:
 
 class TestCompileCacheRestart:
     def test_restart_prewarns_from_cache_zero_fresh_rows(self, tmp_path):
-        """Options.compile_cache_dir (the --compile-cache-dir
-        promotion of KARPENTER_COMPILE_CACHE): the first boot persists
-        the fused program; a restarted service prewarns the fused
-        family with ZERO fresh compile-ledger rows and writes nothing
-        new to the cache."""
+        """Options.compile_cache_dir (--compile-cache-dir): the first
+        boot persists the fused program; a restarted service prewarns
+        the fused family with ZERO fresh compile-ledger rows and writes
+        nothing new to the cache."""
         import jax
+        from jax.experimental.compilation_cache import compilation_cache
 
         from karpenter_tpu.cloudprovider.fake import FakeFactory
         from karpenter_tpu.runtime import KarpenterRuntime, Options
 
         old_dir = jax.config.jax_compilation_cache_dir
         old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+        # conftest keeps the suite cache-free; this test is the cache's
+        jax.config.update("jax_enable_compilation_cache", True)
         try:
             runtime1 = KarpenterRuntime(
                 Options(
@@ -539,7 +541,7 @@ class TestCompileCacheRestart:
             )
             assert jax.config.jax_compilation_cache_dir == str(tmp_path)
             # CPU test compiles finish in <1s; the production threshold
-            # (1s, set by configure_compile_cache) would persist none
+            # (JAX's 1s default) would persist none
             # of them — lower it so this test exercises the disk layer
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", 0.0
@@ -591,24 +593,19 @@ class TestCompileCacheRestart:
                 "the warm reboot must add no new cache entries"
             )
         finally:
+            jax.config.update("jax_enable_compilation_cache", False)
             jax.config.update("jax_compilation_cache_dir", old_dir)
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", old_min
             )
-            try:
-                from jax._src import compilation_cache as _cc
+            compilation_cache.reset_cache()
 
-                _cc.reset_cache()
-            except (ImportError, AttributeError):
-                pass
-
-    def test_flag_wins_over_env(self, tmp_path, monkeypatch):
-        """--compile-cache-dir beats KARPENTER_COMPILE_CACHE (the
-        sidecar precedence), and the parser defaults keep the feature
-        off."""
+    def test_flag_parses_and_defaults_off(self, tmp_path):
+        """--compile-cache-dir parses to the directory and defaults to
+        None (the resolver then picks JAX_COMPILATION_CACHE_DIR or the
+        in-checkout default), and the fused tick defaults off."""
         from karpenter_tpu.__main__ import parse_args
 
-        monkeypatch.setenv("KARPENTER_COMPILE_CACHE", "/env/dir")
         args = parse_args(["--compile-cache-dir", str(tmp_path)])
         assert args.compile_cache_dir == str(tmp_path)
         args = parse_args([])

@@ -155,3 +155,22 @@ class TestIntegration:
             _regex_parse(s)
         t_python = time.perf_counter() - t0
         assert t_native < t_python
+
+
+class TestSourceKeyedBuild:
+    def test_object_name_follows_the_source(self, tmp_path, monkeypatch):
+        """The built object is looked up by a hash of its .c source, so a
+        copied or stale .so built from other source is never loaded,
+        whatever its mtime."""
+        from karpenter_tpu import native as N
+
+        monkeypatch.setattr(N, "_HERE", str(tmp_path))
+        monkeypatch.setattr(N, "_BUILD_DIR", str(tmp_path / "_build"))
+        src = tmp_path / "k.c"
+        src.write_text("int f(void) { return 1; }\n")
+        first = N.built_path("k.c", "_k")
+        assert first == N.built_path("k.c", "_k")
+        src.write_text("int f(void) { return 2; }\n")
+        second = N.built_path("k.c", "_k")
+        assert second != first
+        assert second.startswith(str(tmp_path / "_build" / "_k-"))

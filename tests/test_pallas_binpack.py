@@ -255,9 +255,11 @@ class TestPallasMatchesXLA:
     "interpreter mode",
 )
 class TestCompiledMosaic:
-    """VERDICT r1 weak#3: the Pallas kernel must be proven compiled on
-    hardware, not only interpreted. Run manually on a TPU host with
-    JAX_PLATFORMS unset (the CPU-forced suite skips this)."""
+    """The Pallas kernel compiled by Mosaic on the chip, not only
+    interpreted, equals the XLA program. Run on a TPU host with
+    `KARPENTER_TEST_REAL_BACKEND=1 python -m pytest
+    tests/test_pallas_binpack.py -k Compiled` (the CPU-forced suite skips
+    this; tests/test_tpu_compile.py compiles the kernel without a chip)."""
 
     def test_compiled_equals_xla_on_tpu(self):
         rng = np.random.default_rng(5)

@@ -261,38 +261,37 @@ class TestDecideSplit:
 
 
 class TestCompileCache:
-    def test_configure_sets_jax_flags(self, tmp_path):
+    def test_arm_writes_entries_to_the_flag_directory(
+        self, tmp_path, monkeypatch
+    ):
         """--compile-cache-dir wires JAX's persistent compilation cache
-        (restart survival for the 20-40s TPU solver compiles); empty
-        stays disabled."""
+        (restart survival for the TPU solver compiles): with
+        JAX_COMPILATION_CACHE_DIR unset, a fresh jit lands an entry in
+        the flag's directory (the wiring, not just the flag)."""
         import jax
+        import numpy as np
+        from jax.experimental.compilation_cache import compilation_cache
 
-        from karpenter_tpu.utils.backend import configure_compile_cache
+        from karpenter_tpu.utils.backend import arm_compile_cache
 
-        assert configure_compile_cache("") is False
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         cache = tmp_path / "xla-cache"
-        assert configure_compile_cache(str(cache)) is True
+        old_dir = jax.config.jax_compilation_cache_dir
+        jax.config.update("jax_enable_compilation_cache", True)
         try:
+            assert arm_compile_cache(str(cache)) == str(cache)
             assert jax.config.jax_compilation_cache_dir == str(cache)
-            assert (
-                jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
-            )
-            # functional: with the write threshold floored, a fresh jit
-            # lands an entry in the directory (proves the wiring, not
-            # just the flag)
+            # floor the write thresholds: CPU compiles finish in <1s
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", 0.0
             )
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0
-            )
-            import numpy as np
-
             fn = jax.jit(lambda x: x * 2.0 + 1.0)
             fn(np.arange(8, dtype=np.float32)).block_until_ready()
             assert any(cache.iterdir()), "no cache entry written"
         finally:
-            jax.config.update("jax_compilation_cache_dir", None)
+            jax.config.update("jax_enable_compilation_cache", False)
+            jax.config.update("jax_compilation_cache_dir", old_dir)
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", 1.0
             )
+            compilation_cache.reset_cache()

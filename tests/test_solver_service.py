@@ -869,6 +869,25 @@ class TestShardedDispatch:
             finally:
                 svc.close()
 
+    def test_mesh_build_failure_is_counted_and_served_unsharded(self):
+        """A mesh that cannot be built (here: more devices than exist)
+        is logged and counted in stats.shard_mesh_errors — the number a
+        multi-chip run fails on — while the single-device program
+        serves, and stats.served names the kernel that answered."""
+        svc = SolverService(
+            registry=GaugeRegistry(), shard_threshold=1,
+            shard_mesh_shape=(64, 2),
+        )
+        try:
+            inputs = make_inputs(150, 7, seed=5)
+            out = svc.solve(inputs, backend="xla")
+            assert_outputs_equal(out, B.solve(inputs, backend="xla"))
+            assert svc.stats.shard_mesh_errors == 1
+            assert svc.stats.shard_dispatches == 0
+            assert svc.stats.served == {"solve/xla": 1}
+        finally:
+            svc.close()
+
     def test_shard_failure_degrades_to_single_device_then_sticks(self):
         """The ladder: a shard-path failure re-runs the SAME batch on
         the single-device program (answered on device, NOT from numpy)
