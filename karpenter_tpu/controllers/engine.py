@@ -66,6 +66,7 @@ and the loop is byte-identical to the tick-paced engine.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time as _time
 from typing import Dict, List, Optional, Protocol
@@ -730,25 +731,33 @@ class Manager:
         per-kind reconcile, the HA fleet decide, solver requests, SNG
         actuation — inherits it through the tracer's thread-local
         stack, so one trace connects a watch event to the coalesced
-        dispatch to the provider write it caused."""
+        dispatch to the provider write it caused. The root span covers
+        the tick's work: the reconciles, the solver's gauges and the
+        tick hook, and not the wait for the pass lock."""
         start = _time.perf_counter()
-        # one pass at a time: a tick and an event pass must never
-        # reconcile concurrently (run_event_pass holds the same lock);
-        # with event_driven off the lock is always uncontended
-        with self._pass_lock:
-            now = self.clock()
-            with default_tracer().trace("reconcile.tick"):
+        tracer = default_tracer()
+        with contextlib.ExitStack() as pass_held:
+            # one pass at a time: a tick and an event pass must never
+            # reconcile concurrently (run_event_pass holds the same
+            # lock); with event_driven off it is always uncontended
+            pass_held.enter_context(self._pass_lock)
+            with tracer.trace("reconcile.tick"):
+                now = self.clock()
                 for controller in self._controllers:
                     self._reconcile_controller(controller, now)
-        if self._solver_service is not None:
-            # per-tick dispatch accounting BEFORE the gauges publish:
-            # note_tick closes this tick's window (dispatches since the
-            # last tick -> karpenter_solver_dispatches_per_tick), the
-            # number the fused tick collapses from 3+ to 1
-            self._solver_service.note_tick()
-            self._solver_service.publish_gauges()
-        if self._tick_hook is not None:
-            self._tick_hook()
+                pass_held.close()  # the gauges and the hook run unlocked
+                if self._solver_service is not None:
+                    # per-tick dispatch accounting BEFORE the gauges
+                    # publish: note_tick closes this tick's window
+                    # (dispatches since the last tick ->
+                    # karpenter_solver_dispatches_per_tick), the number
+                    # the fused tick collapses from 3+ to 1
+                    with tracer.span("solver.publish_gauges"):
+                        self._solver_service.note_tick()
+                        self._solver_service.publish_gauges()
+                if self._tick_hook is not None:
+                    with tracer.span("manager.tick_hook"):
+                        self._tick_hook()
         if self._tick_gauge is not None:
             self._tick_gauge.set(
                 "manager", "-", _time.perf_counter() - start

@@ -37,7 +37,7 @@ import numpy as np
 from karpenter_tpu import constraints as _constraints
 from karpenter_tpu.api.metricsproducer import PendingCapacityStatus
 from karpenter_tpu.metrics.registry import GaugeRegistry, default_registry
-from karpenter_tpu.observability import solver_trace
+from karpenter_tpu.observability import default_tracer
 from karpenter_tpu.ops import binpack as B
 from karpenter_tpu.store.columnar import (
     occupancy_from_pods,
@@ -599,8 +599,9 @@ def _dispatch_and_record(  # lint: allow-complexity — the dispatch seam: one g
         # device-puts them itself, and a remote solver serializes host
         # bytes — wrapping in jnp here would force a device round-trip
         # (and JAX init) in the control-plane process the sidecar split
-        # exists to relieve
-        with solver_trace("pendingcapacity.solve"):
+        # exists to relieve. The span stays off the stack: the service's
+        # solver.request is a direct child of reconcile.MetricsProducer
+        with default_tracer().span("pendingcapacity.solve", nest=False):
             out = solver(inputs)
 
         # ONE device->host fetch for all four outputs: device_get

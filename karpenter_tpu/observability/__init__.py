@@ -31,13 +31,13 @@ plane — docs/observability.md is the operator guide:
                      readiness), /debug/traces, /debug/flightrecorder,
                      /debug/decisions, /debug/selfslo, /debug/solver,
                      /debug/profile
-  profiler.py        device-timeline annotations (solver_trace, probed
-                     once), the xprof profiler server, and the bounded
-                     single-flight on-demand capture (/debug/profile)
+  profiler.py        the xprof profiler server and the bounded
+                     single-flight on-demand capture (/debug/profile);
+                     the tracer's spans mirror onto its host plane
 
-The public names below are the pre-package import surface — existing
-importers (`from karpenter_tpu.observability import MetricsServer,
-solver_trace, start_profiler_server`) are unchanged.
+The public names below are the pre-package import surface
+(`from karpenter_tpu.observability import MetricsServer,
+start_profiler_server`).
 """
 
 from karpenter_tpu.observability.devicetelemetry import (
@@ -50,10 +50,7 @@ from karpenter_tpu.observability.flightrecorder import (
     reset_default_flight_recorder,
     set_default_flight_recorder,
 )
-from karpenter_tpu.observability.profiler import (
-    solver_trace,
-    start_profiler_server,
-)
+from karpenter_tpu.observability.profiler import start_profiler_server
 from karpenter_tpu.observability.provenance import (
     DecisionLedger,
     default_ledger,
@@ -86,6 +83,5 @@ __all__ = [
     "set_default_flight_recorder",
     "set_default_ledger",
     "set_default_tracer",
-    "solver_trace",
     "start_profiler_server",
 ]

@@ -1,18 +1,12 @@
-"""JAX-profiler integration: host span annotations + the xprof server.
+"""JAX-profiler integration: the xprof server and on-demand captures.
 
 reference: the reference has NO tracing/profiling (OTel is future work,
 docs/designs/DESIGN.md) — these hooks are an addition the TPU build
 needs: device-side timelines via the JAX profiler (xprof), so a 200 ms
 budget regression is attributable to feed vs compile vs compute. The
-host-side reconcile spans live in observability.tracing; `solver_trace`
-here only mirrors named hot sections onto the DEVICE timeline when a
-profiler is attached.
-
-Hot-path discipline: availability of `jax.profiler` is probed ONCE per
-process and cached — the pre-package implementation re-ran the import
-machinery and built a TraceAnnotation attempt on every call, a real
-cost at thousands of dispatches/sec. The unavailable path now returns a
-shared no-op context manager: zero allocations, one module-global read.
+host side of those timelines is the reconcile tracer
+(observability.tracing): while a capture records, its spans mirror onto
+the profiler's host plane, on the same clock as the device ops.
 """
 
 from __future__ import annotations
@@ -22,69 +16,7 @@ import os
 import threading
 import time as _time
 
-from karpenter_tpu.observability.tracing import _NOOP_SPAN as _NOOP_TRACE
-
-# probe cache: None = unprobed; False = unavailable; otherwise the
-# jax.profiler.TraceAnnotation class itself
-_ANNOTATION_CLS = None
-
-
-class _GuardedAnnotation:
-    """One TraceAnnotation whose SETUP/TEARDOWN failures are swallowed —
-    tracing must never break the solve — while exceptions raised by the
-    traced block itself propagate unchanged."""
-
-    __slots__ = ("_cls", "_name", "_annotation")
-
-    def __init__(self, cls, name: str):
-        self._cls = cls
-        self._name = name
-        self._annotation = None
-
-    def __enter__(self):
-        try:
-            self._annotation = self._cls(self._name)
-            self._annotation.__enter__()
-        except Exception:  # noqa: BLE001 — tracing must never break the solve
-            self._annotation = None
-        return None
-
-    def __exit__(self, *exc):
-        if self._annotation is not None:
-            try:
-                self._annotation.__exit__(None, None, None)
-            except Exception:  # noqa: BLE001
-                pass
-        return False
-
-
-def _probe():
-    """One-time jax.profiler availability probe (cached)."""
-    global _ANNOTATION_CLS
-    if _ANNOTATION_CLS is None:
-        try:
-            import jax.profiler
-
-            _ANNOTATION_CLS = jax.profiler.TraceAnnotation
-        except Exception:  # noqa: BLE001 — no jax / broken profiler
-            _ANNOTATION_CLS = False
-    return _ANNOTATION_CLS
-
-
-def reset_probe() -> None:
-    """Forget the cached probe (test isolation)."""
-    global _ANNOTATION_CLS
-    _ANNOTATION_CLS = None
-
-
-def solver_trace(name: str):
-    """Annotate a host span so it shows up on the device timeline. With
-    no profiler available this is the SHARED no-op context manager —
-    allocation-free, probed once per process."""
-    cls = _ANNOTATION_CLS if _ANNOTATION_CLS is not None else _probe()
-    if cls is False:
-        return _NOOP_TRACE
-    return _GuardedAnnotation(cls, name)
+from karpenter_tpu.observability.tracing import profiler_available
 
 
 def start_profiler_server(port: int = 9999) -> bool:
@@ -149,7 +81,7 @@ def capture_profile(
     Raises ProfileUnavailable when the jax.profiler probe failed and
     ProfileBusy when a capture is already in flight (single-flight) —
     the HTTP surface maps both to 503."""
-    if _probe() is False:
+    if not profiler_available():
         raise ProfileUnavailable("jax.profiler unavailable")
     import jax.profiler
 

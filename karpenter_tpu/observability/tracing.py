@@ -32,12 +32,23 @@ module is the correlation layer:
     publishes the distance as the `karpenter_reconcile_e2e_seconds`
     histogram (metrics/registry.py native histograms).
 
+  * ONE CLOCK WITH THE DEVICE: while a JAX profiler session records
+    (`jax.profiler.start_trace`, `/debug/profile`), every span opened
+    as a context manager also enters a `jax.profiler.TraceAnnotation`
+    of its name, so the spans land on the host plane of the same
+    `.xplane.pb` as the device ops. Whether a session records is
+    `TraceMe.is_enabled()`, probed once per process. `begin()` spans
+    close on another thread and are not mirrored.
+  * COUNTERS (`add`): per-name (count, seconds) accumulated by hot
+    paths too frequent for a span each (store writes, watch fan-out),
+    stamped into the next ROOT span's `args["counters"]` and reset.
+
 Overhead posture: the span ring is a bounded deque; a disabled tracer
 (`enabled = False`) returns a shared no-op context manager and None
-handles — the hot path pays one attribute read. `make bench-trace`
-publishes the enabled-vs-disabled tick overhead (<5% target,
-docs/BENCHMARKS.md); tests/test_observability.py pins a regression
-ceiling.
+handles — the hot path pays one attribute read and the recording
+check. `make bench-trace` publishes the enabled-vs-disabled tick
+overhead (<5% target, docs/BENCHMARKS.md); tests/test_observability.py
+pins a regression ceiling.
 """
 
 from __future__ import annotations
@@ -60,10 +71,67 @@ E2E_BUCKETS = (
 )
 
 
+# the JAX profiler probe, cached per process: None = unprobed, False =
+# unavailable, else (is_recording, TraceAnnotation class)
+_PROFILER = None
+
+
+def _probe_profiler():
+    global _PROFILER
+    if _PROFILER is None:
+        try:
+            import jax.profiler
+            from jax._src.lib import _profiler
+
+            _PROFILER = (
+                _profiler.TraceMe.is_enabled, jax.profiler.TraceAnnotation,
+            )
+        except Exception:  # noqa: BLE001 — no jax / broken profiler
+            _PROFILER = False
+    return _PROFILER
+
+
+def profiler_available() -> bool:
+    return _probe_profiler() is not False
+
+
+def reset_profiler_probe() -> None:
+    """Forget the cached probe (test isolation)."""
+    global _PROFILER
+    _PROFILER = None
+
+
+def _recording():
+    """The probe while a profiler session records, else None."""
+    probe = _PROFILER if _PROFILER is not None else _probe_profiler()
+    return probe if probe is not False and probe[0]() else None
+
+
+def _annotate(name: str):
+    """Enter a TraceAnnotation named `name` while a profiler session
+    records; None when none records. Setup failures are swallowed:
+    tracing must never break the traced work."""
+    probe = _recording()
+    if probe is None:
+        return None
+    try:
+        annotation = probe[1](name)
+        annotation.__enter__()
+        return annotation
+    except Exception:  # noqa: BLE001
+        return None
+
+
+def _leave(annotation) -> None:
+    try:
+        annotation.__exit__(None, None, None)
+    except Exception:  # noqa: BLE001
+        pass
+
+
 class _NoopSpan:
     """Shared allocation-free no-op context manager: the disabled
-    tracer's span AND (via observability.profiler) the profiler-less
-    solver_trace — one class so the two no-op paths cannot diverge."""
+    tracer's span when no profiler session records."""
 
     __slots__ = ()
 
@@ -77,14 +145,42 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+class _MirrorSpan:
+    """A disabled tracer's span while a profiler session records: the
+    TraceAnnotation alone, nothing in the ring."""
+
+    __slots__ = ("name", "_annotation")
+
+    def __init__(self, name):
+        self.name = name
+        self._annotation = None
+
+    def __enter__(self):
+        self._annotation = _annotate(self.name)
+        return None
+
+    def __exit__(self, *exc):
+        if self._annotation is not None:
+            _leave(self._annotation)
+        return False
+
+
+def _disabled_span(name: str):
+    """A disabled tracer's span: the annotation alone while a profiler
+    session records, else the shared no-op."""
+    return _NOOP_SPAN if _recording() is None else _MirrorSpan(name)
+
+
 class OpenSpan:
     """A span in progress. Context-manager use (`with tracer.span(...)`)
-    threads the TLS stack; `begin()`/`close()` use skips it (cross-thread
-    spans must not corrupt another thread's stack)."""
+    threads the TLS stack and mirrors onto a recording profiler;
+    `begin()`/`close()` use skips both (cross-thread spans must not
+    corrupt another thread's stack). `nest=False` context spans mirror
+    but stay off the stack."""
 
     __slots__ = (
         "_tracer", "name", "trace_id", "span_id", "parent_id",
-        "t0", "args", "links", "_on_stack", "_closed",
+        "t0", "args", "links", "_on_stack", "_closed", "_annotation",
     )
 
     def __init__(self, tracer, name, trace_id, span_id, parent_id,
@@ -98,6 +194,7 @@ class OpenSpan:
         self.links = links
         self._on_stack = on_stack
         self._closed = False
+        self._annotation = None
         self.t0 = tracer._clock()
 
     def ref(self) -> Tuple[str, str]:
@@ -116,9 +213,12 @@ class OpenSpan:
     def __enter__(self) -> "OpenSpan":
         if self._on_stack:
             self._tracer._stack().append(self)
+        self._annotation = _annotate(self.name)
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            _leave(self._annotation)
         if self._on_stack:
             stack = self._tracer._stack()
             if stack and stack[-1] is self:
@@ -153,6 +253,10 @@ class Tracer:
         self._observed: Dict[tuple, float] = {}
         self.e2e_observed = 0
         self._c_spans = self._c_dropped = self._h_e2e = None
+        # add() counters pending for the next root close: name ->
+        # [count, seconds], updated in place
+        self._counters: Dict[str, list] = {}
+        self._counter_lock = threading.Lock()
 
     # -- wiring ------------------------------------------------------------
 
@@ -225,21 +329,27 @@ class Tracer:
         """Mint a NEW trace id and open its root span (the watch/tick
         entry points call this)."""
         if not self.enabled:
-            return _NOOP_SPAN
+            return _disabled_span(name)
         return self._open(name, None, True, (), args, on_stack=True)
 
-    def span(self, name: str, parent=None, links: Sequence = (), **args):
+    def span(
+        self, name: str, parent=None, links: Sequence = (),
+        nest: bool = True, **args,
+    ):
         """Open a child span: of `parent` when given (an OpenSpan or a
         (trace_id, span_id) ref), else of this thread's current span;
         with neither, a fresh trace (orphan work is still captured).
         `links` joins other spans' refs — the coalesced-dispatch
-        one-to-many edge."""
+        one-to-many edge. `nest=False` keeps the span off this thread's
+        stack: spans opened inside the block parent to the ENCLOSING
+        span, not to this one (a label around a call whose children
+        belong to the caller)."""
         if not self.enabled:
-            return _NOOP_SPAN
+            return _disabled_span(name)
         if parent is None:
             parent = self.current()
         return self._open(
-            name, parent, False, links, args, on_stack=True
+            name, parent, False, links, args, on_stack=nest
         )
 
     def begin(self, name: str, parent=None, **args) -> Optional[OpenSpan]:
@@ -253,11 +363,45 @@ class Tracer:
             name, parent, False, (), args, on_stack=False
         )
 
+    def add(self, name: str, seconds: float) -> None:
+        """Accumulate one occurrence of `name` taking `seconds` into the
+        counters the next root span carries (module docstring). Safe
+        from any thread; a no-op when disabled."""
+        self.add_all(((name, seconds),))
+
+    def add_all(self, counts) -> None:
+        """`add` for each (name, seconds) of `counts`, under one
+        acquisition of the counter lock (a store write's whole set)."""
+        if not self.enabled:
+            return
+        with self._counter_lock:
+            for name, seconds in counts:
+                counter = self._counters.get(name)
+                if counter is None:
+                    self._counters[name] = [1, seconds]
+                else:
+                    counter[0] += 1
+                    counter[1] += seconds
+
+    def _take_counters(self) -> Optional[dict]:
+        with self._counter_lock:
+            if not self._counters:
+                return None
+            counters, self._counters = self._counters, {}
+        return {
+            name: {"count": count, "seconds": seconds}
+            for name, (count, seconds) in counters.items()
+        }
+
     def _finish(self, span: OpenSpan) -> None:
         now = self._clock()
         args = span.args
         if args:
             args = {k: v for k, v in args.items() if v is not None}
+        if span.parent_id is None and self._counters:
+            counters = self._take_counters()
+            if counters:
+                args = dict(args, counters=counters)
         record = {
             "name": span.name,
             "trace": span.trace_id,
@@ -340,8 +484,12 @@ class Tracer:
         return spans
 
     def clear(self) -> None:
+        """Empty the span ring and drop the counters pending for the
+        next root span."""
         with self._lock:
             self._spans.clear()
+        with self._counter_lock:
+            self._counters = {}
 
     def chrome_events(self) -> List[dict]:
         """Chrome-trace event objects: one complete ("X") event per

@@ -31,7 +31,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -543,7 +543,7 @@ def binpack(inputs: BinPackInputs, buckets: int = DEFAULT_BUCKETS) -> BinPackOut
     )
 
 
-def _fold_for_pallas(inputs: BinPackInputs):
+def fold_for_pallas(inputs: BinPackInputs):
     """(inputs, backend) for the Mosaic path, which predates the
     priority operands. Score-free priority fleets fold the steer
     matrix into the score operand the kernel does understand (with no
@@ -589,14 +589,19 @@ def _fold_for_pallas(inputs: BinPackInputs):
 _put_memo = None
 
 
-def _device_resident(inputs: BinPackInputs) -> BinPackInputs:
+def device_resident(
+    inputs: BinPackInputs, put=None
+) -> Tuple[BinPackInputs, bool]:
+    """(the device copy of `inputs`, whether the memo already held it).
+    On a miss `put` (jax.device_put by default; a caller may time it)
+    makes the copy, and the memo keeps it."""
     global _put_memo
     memo = _put_memo
     if memo is not None and memo[0] is inputs:
-        return memo[1]
-    resident = jax.device_put(inputs)
+        return memo[1], True
+    resident = (put or jax.device_put)(inputs)
     _put_memo = (inputs, resident)
-    return resident
+    return resident, False
 
 
 def solve(
@@ -612,7 +617,7 @@ def solve(
     dominates a CPU solve, while the numpy program's sparse scatters are
     O(P)). All backends are pinned element-for-element equal by
     tests/test_pallas_binpack.py and tests/test_numpy_binpack.py. Inputs
-    are device-cached by object identity (see _device_resident): treat
+    are device-cached by object identity (see device_resident): treat
     them as immutable.
 
     This is the kernel-level entry; production callers submit through the
@@ -630,8 +635,15 @@ def solve(
 
         return binpack_numpy(inputs, buckets=buckets)
     if backend == "pallas":
-        inputs, backend = _fold_for_pallas(inputs)
-    inputs = _device_resident(inputs)
+        inputs, backend = fold_for_pallas(inputs)
+    return solve_resident(device_resident(inputs)[0], buckets, backend)
+
+
+def solve_resident(
+    inputs: BinPackInputs, buckets: int, backend: str
+) -> BinPackOutputs:
+    """The device program ('xla' or 'pallas') on operands already
+    folded (fold_for_pallas) and placed (device_resident)."""
     if backend == "xla":
         return binpack(inputs, buckets=buckets)
     if backend == "pallas":

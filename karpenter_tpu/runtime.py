@@ -146,9 +146,9 @@ class Options:
     # decision provenance ledger (observability/provenance.py,
     # docs/observability.md "Decision provenance"): record the full
     # input chain behind every HA decision into a bounded columnar ring
-    # (/debug/decisions, JSONL next to --trace-export). Default OFF,
-    # matching tracing's posture: provenance is telemetry, and the off
-    # path must stay mark-free (property-pinned byte-identical).
+    # (/debug/decisions, JSONL next to --trace-export). Default OFF
+    # (unlike tracing, which is on): provenance is telemetry, and the
+    # off path must stay mark-free (property-pinned byte-identical).
     provenance: bool = False
     # control-plane self-SLO monitor (observability/selfslo.py): the
     # e2e-latency objective (seconds against the
@@ -160,8 +160,8 @@ class Options:
     # docs/observability.md "Device telemetry & introspection"):
     # compile ledger + compile-storm trips, device memory telemetry +
     # the self-SLO memory source, XLA cost attribution on dispatch
-    # spans, /debug/solver. Default OFF, matching tracing/provenance:
-    # the off path is property-pinned byte-identical and mark-free.
+    # spans, /debug/solver. Default OFF, matching provenance: the off
+    # path is property-pinned byte-identical and mark-free.
     introspect: bool = False
     # compile-cache misses inside ONE tick window (after steady state)
     # that count as a compile storm, and the bytes_in_use/bytes_limit
@@ -219,7 +219,7 @@ class Options:
     # across N leader-elected replicas with fenced handoff. partitions=0
     # (default) builds NOTHING — no lease objects, no lease traffic, no
     # replica metrics: the single-replica wire is byte-identical, per
-    # the tracing/provenance/introspection off-path precedent.
+    # the provenance/introspection off-path precedent.
     partitions: int = 0
     # this replica's identity on the lease plane (--replica-id); None =
     # a generated karpenter-<hex> identity (fine for a single process,

@@ -20,7 +20,7 @@ from typing import Optional
 
 import numpy as np
 
-from karpenter_tpu.observability import solver_trace
+from karpenter_tpu.observability import default_tracer
 from karpenter_tpu.sidecar import codec
 
 SERVICE = "karpenter.solver.v1.Solver"
@@ -41,7 +41,7 @@ def _solve(request: bytes) -> bytes:
     inputs, meta = codec.unpack_dataclass(BinPackInputs, request)
     buckets = int(meta.get("buckets", 32))
     backend = meta.get("backend", "auto")
-    with solver_trace("sidecar.solve"):
+    with default_tracer().span("sidecar.solve"):
         # the shared service owns device access: concurrent RPCs from the
         # gRPC worker pool coalesce into one dispatch, and outputs come
         # back as host numpy ready for the wire
@@ -57,7 +57,7 @@ def _decide(request: bytes) -> bytes:
     from karpenter_tpu.ops.decision import DecisionInputs, decide_jit
 
     inputs, _ = codec.unpack_dataclass(DecisionInputs, request)
-    with solver_trace("sidecar.decide"):
+    with default_tracer().span("sidecar.decide"):
         out = decide_jit(jax.device_put(inputs))
         jax.block_until_ready(out)
     return codec.pack_dataclass(out)

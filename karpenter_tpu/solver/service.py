@@ -90,7 +90,7 @@ import contextlib
 import threading
 import time as _time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -99,7 +99,6 @@ from karpenter_tpu.metrics.registry import GaugeRegistry, default_registry
 from karpenter_tpu.observability import (
     default_flight_recorder,
     default_tracer,
-    solver_trace,
 )
 from karpenter_tpu.ops.binpack import (
     DEFAULT_BUCKETS,
@@ -193,6 +192,7 @@ FORECAST_S_FLOOR = 8
 COMPILE_GRACE_S = 120.0
 
 _STAGE_WINDOW = 256  # per-stage latency ring size (fleet-scale constant)
+
 # native-histogram ladders (docs/observability.md): stage latencies run
 # from sub-ms host work to tens-of-seconds first compiles; coalesce
 # batch sizes follow the power-of-two batch ladder
@@ -206,6 +206,22 @@ _COALESCE_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
 # above it the full window holds so concurrent bursts keep coalescing.
 _LOAD_ALPHA = 0.5
 _LOAD_IDLE = 1.5
+
+
+def _p50_p99(samples) -> Tuple[float, float]:
+    """The 50th and 99th percentiles of a latency ring, interpolated
+    linearly as np.percentile does by default. Sorting at most
+    _STAGE_WINDOW floats costs less than np.percentile's per-call
+    overhead, and the worker refreshes these gauges after every batch,
+    holding the interpreter lock the callers' threads need."""
+    ordered = sorted(samples)
+    top = len(ordered) - 1
+
+    def at(x: float) -> float:
+        i = int(x)
+        return ordered[i] + (ordered[min(i + 1, top)] - ordered[i]) * (x - i)
+
+    return at(0.50 * top), at(0.99 * top)
 
 
 class SolverSaturated(RuntimeError):
@@ -626,19 +642,13 @@ class SolverService:
         )
         with self._stage_lock:
             snapshot = {k: list(v) for k, v in self._stages.items()}
-        uploads = snapshot.get("upload")
-        if uploads:
-            self._g_upload.set(
-                "-", "-", float(np.percentile(uploads, 50))
-            )
         for stage, samples in snapshot.items():
             if samples:
-                self._g_stage_p50.set(
-                    stage, "-", float(np.percentile(samples, 50))
-                )
-                self._g_stage_p99.set(
-                    stage, "-", float(np.percentile(samples, 99))
-                )
+                p50, p99 = _p50_p99(samples)
+                self._g_stage_p50.set(stage, "-", p50)
+                self._g_stage_p99.set(stage, "-", p99)
+                if stage == "upload":
+                    self._g_upload.set("-", "-", p50)
 
     def note_tick(self) -> None:
         """Per-tick dispatch accounting behind the
@@ -654,12 +664,19 @@ class SolverService:
         self._g_dispatches_tick.set("-", "-", float(delta))
 
     @contextlib.contextmanager
-    def track(self, stage: str):
+    def track(self, stage: str, span: Optional[str] = None, parent=None):
         """Record an arbitrary caller stage (e.g. the HA controller's
-        fleet decide) into the service's latency surface."""
+        fleet decide) into the service's latency surface. With `span`,
+        the block is also that tracer span (a child of `parent` when
+        given, else of this thread's current span): the bin-pack's
+        host stages are timed at one boundary for both."""
         t0 = _time.perf_counter()
         try:
-            yield
+            if span is None:
+                yield
+            else:
+                with default_tracer().span(span, parent=parent):
+                    yield
         finally:
             self._record_stage(stage, _time.perf_counter() - t0)
 
@@ -874,15 +891,16 @@ class SolverService:
         rings — the per-stage breakdown bench.py --hotpath publishes."""
         with self._stage_lock:
             snapshot = {k: list(v) for k, v in self._stages.items()}
-        return {
-            stage: {
-                "p50_ms": round(float(np.percentile(samples, 50)), 4),
-                "p99_ms": round(float(np.percentile(samples, 99)), 4),
-                "n": len(samples),
-            }
-            for stage, samples in snapshot.items()
-            if samples
-        }
+        out = {}
+        for stage, samples in snapshot.items():
+            if samples:
+                p50, p99 = _p50_p99(samples)
+                out[stage] = {
+                    "p50_ms": round(p50, 4),
+                    "p99_ms": round(p99, 4),
+                    "n": len(samples),
+                }
+        return out
 
     # -- submission -------------------------------------------------------
 
@@ -1487,13 +1505,12 @@ class SolverService:
 
             try:
                 with default_tracer().span("solver.cost", backend=resolved):
-                    with solver_trace("solver.cost"):
-                        # the cost-path fault-injection point: an error
-                        # plan exercises the cost-blind degradation +
-                        # FSM trip (docs/resilience.md)
-                        inject("cost.score")
-                        out = CK.cost_jit(inputs)
-                        jax.block_until_ready(out)
+                    # the cost-path fault-injection point: an error
+                    # plan exercises the cost-blind degradation +
+                    # FSM trip (docs/resilience.md)
+                    inject("cost.score")
+                    out = CK.cost_jit(inputs)
+                    jax.block_until_ready(out)
             except Exception:
                 self._record_device_failure()
                 raise
@@ -1558,13 +1575,12 @@ class SolverService:
                     with default_tracer().span(
                         "solver.poolgroup", backend=resolved
                     ):
-                        with solver_trace("solver.poolgroup"):
-                            # the joint-path fault-injection point: an
-                            # error plan exercises the independent-
-                            # ladder degradation + FSM trip
-                            inject("poolgroup.solve")
-                            out = PGK.poolgroup_jit(inputs)
-                            jax.block_until_ready(out)
+                        # the joint-path fault-injection point: an
+                        # error plan exercises the independent-
+                        # ladder degradation + FSM trip
+                        inject("poolgroup.solve")
+                        out = PGK.poolgroup_jit(inputs)
+                        jax.block_until_ready(out)
                     self._record_device_success()
                     self.stats.poolgroup_dispatches += 1
                     self._count_dispatch()
@@ -1635,10 +1651,9 @@ class SolverService:
                     import jax
 
                     with default_tracer().span(span, backend=resolved):
-                        with solver_trace(span):
-                            inject("simlab.step")
-                            out = device_fn(inputs)
-                            jax.block_until_ready(out)
+                        inject("simlab.step")
+                        out = device_fn(inputs)
+                        jax.block_until_ready(out)
                     self._record_device_success()
                     self.stats.sim_dispatches += 1
                     self._count_dispatch()
@@ -1755,13 +1770,12 @@ class SolverService:
                     "solver.fused_tick", backend=resolved,
                     **self._span_cost_args(key),
                 ):
-                    with solver_trace("solver.fused_tick"):
-                        # the fused-path fault-injection point: an
-                        # error plan exercises the fused → chained →
-                        # numpy ladder + FSM trip (docs/resilience.md)
-                        inject("fused.tick")
-                        out = FT.fused_tick_jit(inputs)
-                        jax.block_until_ready(out)
+                    # the fused-path fault-injection point: an
+                    # error plan exercises the fused → chained →
+                    # numpy ladder + FSM trip (docs/resilience.md)
+                    inject("fused.tick")
+                    out = FT.fused_tick_jit(inputs)
+                    jax.block_until_ready(out)
                 self._note_fresh_compile(
                     fresh, "fused", key, t0, [], cost_fn=cost_fn,
                 )
@@ -1781,8 +1795,7 @@ class SolverService:
             with default_tracer().span(
                 "solver.fused_tick", backend="chained"
             ):
-                with solver_trace("solver.fused_tick.chained"):
-                    out = FT.fused_tick_chained(inputs)
+                out = FT.fused_tick_chained(inputs)
             # a degraded serve: stage dispatches are counted (the
             # dispatches-per-tick gauge must show the real program
             # count) but the FSM keeps counting fused failures — a
@@ -1849,8 +1862,7 @@ class SolverService:
         t0 = _time.perf_counter()
         try:
             with default_tracer().span("solver.decide"):
-                with solver_trace("solver.decide"):
-                    out = self._decide_dispatch(inputs)
+                out = self._decide_dispatch(inputs)
             # the decide kernel has no numpy mirror: it is served by
             # the in-process jitted program ("device": XLA on whatever
             # backend jax resolved) or across the gRPC split
@@ -2369,7 +2381,7 @@ class SolverService:
         arrows)."""
         tracer = default_tracer()
         if not tracer.enabled:
-            return tracer.span(name)  # the shared no-op span
+            return tracer.span(name)  # the no-op (or profiler-only) span
         spans = [r.span for r in live if r.span is not None]
         return tracer.span(
             name,
@@ -2574,7 +2586,7 @@ class SolverService:
             with self._device_section(
                 live, grace=COMPILE_GRACE_S if fresh else 0.0
             ):
-                with solver_trace("solver.forecast"):
+                with default_tracer().span("solver.forecast"):
                     # the forecast-path fault-injection point
                     # (faults/registry.py, docs/resilience.md): an error
                     # plan exercises the numpy degradation + FSM, a hang
@@ -2677,7 +2689,7 @@ class SolverService:
                 [request], **self._span_cost_args(cache_key),
             ):
                 with self._device_section([request], grace=grace):
-                    with solver_trace("solver.preempt"):
+                    with default_tracer().span("solver.preempt"):
                         # the preempt-path fault-injection point
                         # (faults/registry.py, docs/resilience.md): an
                         # error plan exercises the numpy degradation +
@@ -2738,6 +2750,12 @@ class SolverService:
         return fn, fresh
 
     def _solve_pallas(self, shape, buckets: int, live: List[_Request]) -> None:
+        """The single-chip Mosaic solve, one request at a time, in the
+        XLA path's stages and spans, all in the request's trace: pad
+        (bucket padding and the operand fold), dispatch (transfer to
+        block_until_ready) enclosing upload, then scatter (the fetch and
+        crop). Operands the one-slot identity memo (ops/binpack) already
+        holds record an upload of 0.0."""
         import jax
 
         from karpenter_tpu.ops import binpack as B
@@ -2746,14 +2764,23 @@ class SolverService:
         fresh = self._count_compile(cache_key)
         grace = COMPILE_GRACE_S if fresh else 0.0
         for request in live:
-            padded = pad_to_bucket(request.inputs, shape)
+            with self.track("pad", "solver.pad", request.span):
+                padded, kernel = B.fold_for_pallas(
+                    pad_to_bucket(request.inputs, shape)
+                )
             t0 = _time.perf_counter()
-            with self._device_section([request], grace=grace):
-                out = B.solve(padded, buckets=buckets, backend="pallas")
-                jax.block_until_ready(out)
+            with self._dispatch_span("solver.dispatch", [request]):
+                with self._device_section([request], grace=grace):
+                    operands, held = B.device_resident(
+                        padded, put=lambda tree: self._upload(tree, wait=False)
+                    )
+                    if held:
+                        self._record_stage("upload", 0.0)
+                    out = B.solve_resident(operands, buckets, kernel)
+                    jax.block_until_ready(out)
             grace = 0.0  # only the first call of the batch compiles
             if fresh:
-                # no jit handle to lower here (B.solve resolves the
+                # no jit handle to lower here (B.solve_resident resolves the
                 # fused Mosaic kernel internally), so the ledger row
                 # carries the wall time without cost attribution; the
                 # helper's stale check keeps a watchdog-superseded
@@ -2764,7 +2791,9 @@ class SolverService:
                 fresh = False
             self._record_stage("dispatch", _time.perf_counter() - t0)
             self._count_dispatch()
-            request.finish(result=self._crop_host(out, request))
+            with self.track("scatter", "solver.scatter", request.span):
+                result = self._crop_host(out, request)
+            request.finish(result=result)
 
     def _begin_batched_xla(
         self, shape, buckets: int, live: List[_Request],
@@ -2822,10 +2851,9 @@ class SolverService:
             with self._device_section(
                 live, grace=COMPILE_GRACE_S if fresh else 0.0
             ):
-                with solver_trace("solver.dispatch"):
-                    if resident is None:
-                        stacked = self._upload(stacked)
-                    out = fn(stacked, buckets)
+                if resident is None:
+                    stacked = self._upload(stacked)
+                out = fn(stacked, buckets)
         self._note_fresh_compile(
             fresh, "solve", cache_key, t0, live, cost_fn=cost_fn
         )
@@ -2853,12 +2881,11 @@ class SolverService:
         request, the cheapest valid filler (its outputs are computed
         and discarded). Shared by the single-device and sharded
         dispatch paths; records the "pad" stage."""
-        t0 = _time.perf_counter()
-        padded = [pad_to_bucket(r.inputs, shape) for r in live]
-        n_batch = bucket_up(len(padded), 1)
-        padded.extend(padded[:1] * (n_batch - len(padded)))
-        stacked = _stack_inputs(padded)
-        self._record_stage("pad", _time.perf_counter() - t0)
+        with self.track("pad", "solver.pad", _first_span(live)):
+            padded = [pad_to_bucket(r.inputs, shape) for r in live]
+            n_batch = bucket_up(len(padded), 1)
+            padded.extend(padded[:1] * (n_batch - len(padded)))
+            stacked = _stack_inputs(padded)
         return stacked, n_batch
 
     def _resident_stack(
@@ -2890,7 +2917,7 @@ class SolverService:
         try:
             stacked, kind = self._resident.obtain(
                 request.inputs, shape, mode,
-                lambda tree: self._upload(tree, shardings),
+                lambda tree: self._upload(tree, shardings, request.span),
                 tenant=request.tenant,
                 now=self._clock(),
             )
@@ -2915,22 +2942,25 @@ class SolverService:
         self.stats.resident_drops = self._resident.drops
         return stacked
 
-    def _upload(self, stacked, shardings=None):
+    def _upload(self, stacked, shardings=None, parent=None, wait=True):
         """device_put the stack (with NamedShardings on the sharded
         path) and record the ISOLATED host->device transfer cost (the
         device-resident-state target, ROADMAP item 4): compute waits on
         the transfer either way, so the sync point only moves the wait
-        to where it can be measured."""
+        to where it can be measured. wait=False (the one-chip Mosaic
+        path) times device_put alone, the host side of the transfer:
+        there the sync point before the launch cost 9% of the solves a
+        window on the v5e (PERF.md)."""
         import jax
 
-        t_up = _time.perf_counter()
-        stacked = (
-            jax.device_put(stacked)
-            if shardings is None
-            else jax.device_put(stacked, shardings)
-        )
-        jax.block_until_ready(stacked)
-        self._record_stage("upload", _time.perf_counter() - t_up)
+        with self.track("upload", "solver.upload", parent):
+            stacked = (
+                jax.device_put(stacked)
+                if shardings is None
+                else jax.device_put(stacked, shardings)
+            )
+            if wait:
+                jax.block_until_ready(stacked)
         return stacked
 
     def _sharded_xla(
@@ -2996,7 +3026,7 @@ class SolverService:
             with self._device_section(
                 live, grace=COMPILE_GRACE_S if fresh else 0.0
             ):
-                with solver_trace("solver.shard"):
+                with default_tracer().span("solver.shard"):
                     if resident is None:
                         stacked = self._upload(stacked, shardings)
                     out = fn(stacked, buckets)
@@ -3010,16 +3040,15 @@ class SolverService:
         self._record_stage("dispatch", _time.perf_counter() - t0)
         self._count_dispatch()
         self.stats.shard_dispatches += 1
-        t0 = _time.perf_counter()
-        host = _fetch_outputs(out)
-        for i, request in enumerate(live):
-            request.finish(
-                result=crop_outputs(
-                    _index_outputs(host, i),
-                    request.n_pods, request.n_groups,
+        with self.track("scatter", "solver.scatter", _first_span(live)):
+            host = _fetch_outputs(out)
+            for i, request in enumerate(live):
+                request.finish(
+                    result=crop_outputs(
+                        _index_outputs(host, i),
+                        request.n_pods, request.n_groups,
+                    )
                 )
-            )
-        self._record_stage("scatter", _time.perf_counter() - t0)
         self._record_device_success()
 
     def _drain_one(self) -> None:
@@ -3050,13 +3079,14 @@ class SolverService:
             self._record_stage(
                 "dispatch", _time.perf_counter() - t_dispatch
             )
-            t0 = _time.perf_counter()
-            host = _fetch_outputs(out)
-            for i, request in enumerate(live):
-                request.finish(
-                    result=self._crop_host(_index_outputs(host, i), request)
-                )
-            self._record_stage("scatter", _time.perf_counter() - t0)
+            with self.track("scatter", "solver.scatter", _first_span(live)):
+                host = _fetch_outputs(out)
+                for i, request in enumerate(live):
+                    request.finish(
+                        result=self._crop_host(
+                            _index_outputs(host, i), request
+                        )
+                    )
             self._record_device_success()
         except Exception as error:  # noqa: BLE001 — device failure path
             if not self._stale():
@@ -3200,6 +3230,12 @@ class SolverService:
         from karpenter_tpu.ops.numpy_binpack import binpack_numpy
 
         return binpack_numpy(inputs, buckets=buckets)
+
+
+def _first_span(live: List[_Request]):
+    """The first rider's request span (None untraced): a coalesced
+    batch's host stages join its trace, as its dispatch span does."""
+    return next((r.span for r in live if r.span is not None), None)
 
 
 def _stack_inputs(padded: List[BinPackInputs]) -> BinPackInputs:

@@ -259,13 +259,13 @@ class DurableStore(Store):
 
     # -- journaling --------------------------------------------------------
 
-    def _notify(self, event: str, obj) -> None:
+    def _notify(self, event: str, obj, t0) -> None:
         # called under the store lock at every mutation, with the stored
         # (post-mutation) object — journal BEFORE watchers observe, so a
         # crash between the two replays a superset of what watchers saw
         if not self._recovering:
             self._append({"event": event, "object": encode_object(obj)})
-        super()._notify(event, obj)
+        super()._notify(event, obj, t0)
 
     def _append(self, record: dict) -> None:
         """Journal one record. Never raises: memory is authoritative and

@@ -21,15 +21,29 @@ documented watcher contract (read-only) is what makes a 1%-churn tick over
 Durability mirrors the reference's checkpoint/resume story (SURVEY.md §5):
 ALL durable state lives in object spec/status here; controllers and the
 device solver are stateless and resume by re-listing.
+
+Ingest is counted, not spanned (a span per write would flood the
+tracer's ring): every write that notifies watchers (create, update,
+patch_status, delete, apply_event, update_scale) adds
+`store.write.<Kind>` (from its entry to the end of its fan-out),
+`store.notify.<Kind>` (the fan-out alone) and, for each watcher
+callback, `watch.<owner>` to the reconcile tracer's counters
+(observability/tracing.py `Tracer.add_all`, one call a write), which
+the next root span carries. A disabled tracer skips the clock reads
+too.
 """
 
 from __future__ import annotations
 
 import threading
+import time as _time
 
+from karpenter_tpu.observability.tracing import default_tracer
 from karpenter_tpu.utils.clone import fast_clone
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
+
+_clock = _time.perf_counter
 
 ADDED = "Added"
 MODIFIED = "Modified"
@@ -77,12 +91,40 @@ def _key(obj) -> Tuple[str, str, str]:
     return (_kind_of(obj), obj.metadata.namespace, obj.metadata.name)
 
 
+# kind -> (store.write.<Kind>, store.notify.<Kind>): the counter names,
+# formatted once per kind, never per write
+_COUNTER_NAMES: Dict[str, Tuple[str, str]] = {}
+
+
+def _counter_names(kind: str) -> Tuple[str, str]:
+    names = _COUNTER_NAMES.get(kind)
+    if names is None:
+        names = _COUNTER_NAMES[kind] = (
+            "store.write." + kind, "store.notify." + kind,
+        )
+    return names
+
+
+def _write_start() -> Optional[float]:
+    """A write's start on the counters' clock, or None while the tracer
+    is disabled (nothing is counted, and the clock is not read)."""
+    return _clock() if default_tracer().enabled else None
+
+
+def _watch_owner(callback) -> str:
+    """`watch.<qualified name>` of a watcher (a partial's function)."""
+    func = getattr(callback, "func", callback)
+    name = getattr(func, "__qualname__", None) or type(func).__qualname__
+    return "watch." + name
+
+
 class Store:
     def __init__(self):
         self._lock = threading.RLock()
         self._objects: Dict[Tuple[str, str, str], object] = {}
         self._rv = 0
-        self._watchers: List[Tuple[Optional[str], Callable]] = []
+        # (kind or None, callback, its watch.<owner> counter name)
+        self._watchers: List[Tuple[Optional[str], Callable, str]] = []
         # spec.nodeName index for Pods
         self._pods_by_node: Dict[str, set] = {}
         # kind -> insertion-ordered keys: list(kind)/keys(kind) must
@@ -100,14 +142,31 @@ class Store:
         instance can never change after delivery) — treat it as strictly
         read-only."""
         with self._lock:
-            self._watchers.append((kind, callback))
+            self._watchers.append((kind, callback, _watch_owner(callback)))
 
-    def _notify(self, event: str, obj) -> None:
+    def _notify(self, event: str, obj, t0: Optional[float]) -> None:
+        """Fan `obj` out to its watchers. `t0` is the write's start
+        (`_write_start`): when it is not None, the write's counters are
+        added in one call, the write ending with its fan-out."""
         # obj is the stored (immutable-after-insert) instance: no copy
         kind = _kind_of(obj)
-        for want_kind, callback in list(self._watchers):
+        if t0 is None:
+            for want_kind, callback, _ in list(self._watchers):
+                if want_kind is None or want_kind == kind:
+                    callback(event, obj)
+            return
+        counts = []
+        start = mark = _clock()
+        for want_kind, callback, owner in list(self._watchers):
             if want_kind is None or want_kind == kind:
                 callback(event, obj)
+                now = _clock()
+                counts.append((owner, now - mark))
+                mark = now
+        write, notify = _counter_names(kind)
+        counts.append((notify, mark - start))
+        counts.append((write, mark - t0))
+        default_tracer().add_all(counts)
 
     # -- index maintenance ------------------------------------------------
 
@@ -151,6 +210,7 @@ class Store:
         CALLER's object is stamped in place with the minted identity
         (uid, creationTimestamp, resourceVersion) and returned — one
         clone per create, on the watch-fan-out hot path."""
+        t0 = _write_start()
         with self._lock:
             key = _key(obj)
             if key in self._objects:
@@ -168,7 +228,7 @@ class Store:
             stored = fast_clone(obj)
             self._objects[key] = stored
             self._index_add(stored)
-            self._notify(ADDED, stored)
+            self._notify(ADDED, stored, t0)
             return obj
 
     def get(self, kind: str, namespace: str, name: str):
@@ -189,6 +249,7 @@ class Store:
         Optimistic concurrency: a stale resource_version is rejected so a
         slow writer cannot silently clobber a concurrent change (e.g. the
         autoscaler's scale write)."""
+        t0 = _write_start()
         with self._lock:
             key = _key(obj)
             stored = self._objects.get(key)
@@ -210,7 +271,7 @@ class Store:
             new = fast_clone(obj)
             self._objects[key] = new
             self._index_replace(stored, new)
-            self._notify(MODIFIED, new)
+            self._notify(MODIFIED, new, t0)
             return obj
 
     def patch_status(self, obj):
@@ -224,6 +285,7 @@ class Store:
         from karpenter_tpu.faults import inject
 
         inject("store.patch_status")
+        t0 = _write_start()
         with self._lock:
             key = _key(obj)
             stored = self._objects.get(key)
@@ -236,10 +298,11 @@ class Store:
             self._rv += 1
             new.metadata.resource_version = self._rv
             self._objects[key] = new
-            self._notify(MODIFIED, new)
+            self._notify(MODIFIED, new, t0)
             return fast_clone(new)
 
     def delete(self, obj_or_kind, namespace: Optional[str] = None, name=None):
+        t0 = _write_start()
         with self._lock:
             if isinstance(obj_or_kind, str):
                 key = (obj_or_kind, namespace, name)
@@ -249,7 +312,7 @@ class Store:
             if stored is None:
                 raise NotFoundError(f"{key} not found")
             self._index_remove(stored)
-            self._notify(DELETED, stored)
+            self._notify(DELETED, stored, t0)
 
     def keys(self, kind: str) -> list:
         """(kind, namespace, name) keys of a kind, without copying objects."""
@@ -290,6 +353,7 @@ class Store:
         watch stream) verbatim: no identity minting, no resourceVersion
         bump or conflict check — the upstream's metadata IS the truth.
         Watchers observe it exactly like a local mutation."""
+        t0 = _write_start()
         with self._lock:
             key = _key(obj)
             stored = self._objects.get(key)
@@ -298,7 +362,7 @@ class Store:
                     return
                 del self._objects[key]
                 self._index_remove(stored)
-                self._notify(DELETED, stored)
+                self._notify(DELETED, stored, t0)
                 return
             if (
                 stored is not None
@@ -317,7 +381,7 @@ class Store:
                 # (k8s API conventions); only numeric ones can advance the
                 # local minting counter, and equality above never needs more
                 self._rv = max(self._rv, obj.metadata.resource_version)
-            self._notify(MODIFIED if stored is not None else ADDED, obj)
+            self._notify(MODIFIED if stored is not None else ADDED, obj, t0)
 
     # -- scale subresource -------------------------------------------------
 
@@ -358,6 +422,7 @@ class Store:
     def update_scale(
         self, kind: str, scale: Scale, api_version: str = ""
     ) -> None:
+        t0 = _write_start()
         with self._lock:
             obj = self._objects.get((kind, scale.namespace, scale.name))
             if obj is None:
@@ -371,7 +436,7 @@ class Store:
             self._rv += 1
             new.metadata.resource_version = self._rv
             self._objects[(kind, scale.namespace, scale.name)] = new
-            self._notify(MODIFIED, new)
+            self._notify(MODIFIED, new, t0)
 
 
 _DUCK_SCALE_HOOKS = _ScaleHooks(

@@ -507,7 +507,7 @@ class TestSolveCaching:
     generation), encode memo (same BinPackInputs object per unchanged
     fleet), and their invalidation on pod/node/producer churn. These are
     what turn an unchanged 100k-pod tick into a single device round-trip
-    (see _dispatch_and_record's packed fetch + ops/binpack._device_resident)."""
+    (see _dispatch_and_record's packed fetch + ops/binpack.device_resident)."""
 
     def test_snapshot_identity_stable_until_mutation(self):
         store = Store()

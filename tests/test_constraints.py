@@ -762,7 +762,7 @@ class TestPallasGuard:
         pods, profiles, groups = _random_world(rng)
         inputs = _encode(pods, profiles, groups)
         assert B.has_constraint_operands(inputs)
-        _, route = B._fold_for_pallas(inputs)
+        _, route = B.fold_for_pallas(inputs)
         assert route == "xla"
 
     def test_service_reroutes_and_counts(self):

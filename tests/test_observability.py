@@ -14,8 +14,12 @@ The acceptance pins (ISSUE 9 / docs/observability.md):
     consistency, label escaping) and MetricsServer content-type/404;
   * /readyz reflects REAL state (503 in recovery warm-up / solver FSM
     tripped), /healthz stays liveness-only;
-  * solver_trace probes jax.profiler ONCE and the unavailable path is
-    allocation-free (the shared no-op);
+  * tracer counters (`Tracer.add`) ride root spans only, and store
+    ingest adds them without a span;
+  * the resync tick's root span encloses its gauges and tick hook, and
+    solver.request stays a direct child of reconcile.MetricsProducer;
+  * the single-chip Pallas solve records pad, upload, dispatch and
+    scatter in the request's trace;
   * tracing-enabled tick overhead stays bounded (the structural guard;
     `make bench-trace` publishes the honest <5% number).
 """
@@ -44,7 +48,6 @@ from karpenter_tpu.observability import (
     set_default_flight_recorder,
     set_default_tracer,
 )
-from karpenter_tpu.observability import profiler as profiler_mod
 
 
 class FakeClock:
@@ -340,40 +343,6 @@ class TestFlightRecorder:
         assert doc["events"][-1]["trace_ids"] == ["t1"]
 
 
-# -- solver_trace probe caching ----------------------------------------------
-
-
-class TestSolverTraceProbe:
-    def test_unavailable_path_is_shared_noop(self, monkeypatch):
-        monkeypatch.setattr(profiler_mod, "_ANNOTATION_CLS", False)
-        a = profiler_mod.solver_trace("x")
-        b = profiler_mod.solver_trace("y")
-        assert a is b is profiler_mod._NOOP_TRACE  # allocation-free
-
-    def test_probe_runs_once(self, monkeypatch):
-        monkeypatch.setattr(profiler_mod, "_ANNOTATION_CLS", None)
-        calls = {"n": 0}
-        real = profiler_mod._probe
-
-        def counting():
-            calls["n"] += 1
-            return real()
-
-        monkeypatch.setattr(profiler_mod, "_probe", counting)
-        with profiler_mod.solver_trace("a"):
-            pass
-        probed = profiler_mod._ANNOTATION_CLS
-        assert probed is not None  # cached (class or False)
-        with profiler_mod.solver_trace("b"):
-            pass
-        assert calls["n"] == 1  # second call hit the cache
-
-    def test_exceptions_propagate(self):
-        with pytest.raises(RuntimeError):
-            with profiler_mod.solver_trace("x"):
-                raise RuntimeError("from the traced block")
-
-
 # -- exposition conformance (promtool-style lint) ----------------------------
 
 
@@ -617,17 +586,17 @@ class TestMetricsServer:
 # -- solver service integration ----------------------------------------------
 
 
-def _binpack_inputs(n_pods=3, n_groups=2):
+def _binpack_inputs(n_pods=3, n_groups=2, resources=2, taints=4, labels=4):
     from karpenter_tpu.ops.binpack import BinPackInputs
 
     return BinPackInputs(
-        pod_requests=np.ones((n_pods, 2), np.float32),
+        pod_requests=np.ones((n_pods, resources), np.float32),
         pod_valid=np.ones(n_pods, bool),
-        pod_intolerant=np.zeros((n_pods, 4), bool),
-        pod_required=np.zeros((n_pods, 4), bool),
-        group_allocatable=np.full((n_groups, 2), 8.0, np.float32),
-        group_taints=np.zeros((n_groups, 4), bool),
-        group_labels=np.ones((n_groups, 4), bool),
+        pod_intolerant=np.zeros((n_pods, taints), bool),
+        pod_required=np.zeros((n_pods, labels), bool),
+        group_allocatable=np.full((n_groups, resources), 8.0, np.float32),
+        group_taints=np.zeros((n_groups, taints), bool),
+        group_labels=np.ones((n_groups, labels), bool),
     )
 
 
@@ -770,6 +739,335 @@ class TestSolverTracing:
         assert all(
             s["args"].get("degraded") is True for s in request_spans
         )
+
+
+class TestPallasStages:
+    """The single-chip Mosaic path (interpret mode on the CPU) records
+    the XLA path's stages, each under its span in the request's trace."""
+
+    @staticmethod
+    def _service():
+        from karpenter_tpu.solver import SolverService
+
+        service = SolverService(registry=GaugeRegistry(), backend="pallas")
+        stages = []
+        record = service._record_stage
+        service._record_stage = lambda stage, seconds: (
+            stages.append((stage, seconds)), record(stage, seconds)
+        )
+        return service, stages
+
+    def test_submit_records_each_stage_once(self, fresh_tracer):
+        service, stages = self._service()
+        try:
+            service.submit(_binpack_inputs(), buckets=8).result()
+        finally:
+            service.close()
+        names = [stage for stage, _ in stages if stage != "queue_wait"]
+        assert names == ["pad", "upload", "dispatch", "scatter"]
+        spans = {s["name"]: s for s in fresh_tracer.snapshot()}
+        request = spans["solver.request"]
+        for name in ("solver.pad", "solver.dispatch", "solver.scatter"):
+            assert spans[name]["parent"] == request["id"], name
+        assert spans["solver.upload"]["parent"] == spans["solver.dispatch"]["id"]
+        assert spans["solver.dispatch"]["links"] == [request["id"]]
+        assert {s["trace"] for s in spans.values()} == {request["trace"]}
+
+    def test_unchanged_inputs_record_upload_zero(self, fresh_tracer):
+        from karpenter_tpu.solver.bucketing import bucket_shape
+
+        # operands already at their bucket shape: padding returns the
+        # same object, so the identity memo sees it again
+        p, t, r, k, l = bucket_shape(_binpack_inputs())
+        inputs = _binpack_inputs(n_pods=p, n_groups=t, resources=r,
+                                 taints=k, labels=l)
+        assert bucket_shape(inputs) == (p, t, r, k, l)
+        service, stages = self._service()
+        try:
+            for _ in range(2):
+                service.submit(inputs, buckets=8).result()
+        finally:
+            service.close()
+        uploads = [seconds for stage, seconds in stages if stage == "upload"]
+        assert len(uploads) == 2
+        assert uploads[0] > 0.0 and uploads[1] == 0.0
+        # a memo hit opens no upload span
+        names = [s["name"] for s in fresh_tracer.snapshot()]
+        assert names.count("solver.upload") == 1
+        assert names.count("solver.dispatch") == 2
+
+
+class TestTracerCounters:
+    def test_counters_ride_root_spans_then_reset(self):
+        tracer = Tracer()
+        tracer.add("store.write.Pod", 0.25)
+        with tracer.trace("reconcile.tick"):
+            tracer.add("store.write.Pod", 0.5)
+            with tracer.span("reconcile.MetricsProducer"):
+                pass
+        with tracer.trace("reconcile.tick"):
+            pass
+        child, first, second = tracer.snapshot()
+        assert "counters" not in child["args"]
+        assert first["args"]["counters"] == {
+            "store.write.Pod": {"count": 2, "seconds": 0.75},
+        }
+        assert "counters" not in second["args"]
+
+    def test_disabled_tracer_counts_nothing(self):
+        tracer = Tracer()
+        tracer.enabled = False
+        tracer.add("store.write.Pod", 1.0)
+        tracer.enabled = True
+        with tracer.trace("reconcile.tick"):
+            pass
+        assert "counters" not in tracer.snapshot()[0]["args"]
+
+    def test_clear_drops_pending_counters(self):
+        tracer = Tracer()
+        tracer.add("watch.Manager._on_event", 1.0)
+        tracer.clear()
+        with tracer.trace("reconcile.tick"):
+            pass
+        assert "counters" not in tracer.snapshot()[0]["args"]
+
+    def test_add_from_threads_at_once(self):
+        """The watch thread and the pass thread add at once: a lost
+        read-modify-write would show as a short count."""
+        import sys
+
+        tracer = Tracer()
+        n_threads, n_adds = 2 * (os.cpu_count() or 4), 2000
+
+        def hammer():
+            for _ in range(n_adds):
+                tracer.add("store.notify.Pod", 1e-6)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=hammer) for _ in range(n_threads)]
+            for thread in threads:
+                thread.start()
+            for thread in threads:
+                thread.join(timeout=30)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(thread.is_alive() for thread in threads)
+        with tracer.trace("reconcile.tick"):
+            pass
+        counter = tracer.snapshot()[0]["args"]["counters"]["store.notify.Pod"]
+        assert counter["count"] == n_threads * n_adds
+        assert counter["seconds"] == pytest.approx(n_threads * n_adds * 1e-6)
+
+
+class _Watcher:
+    def __init__(self):
+        self.events = []
+
+    def on_event(self, event, obj):
+        self.events.append(event)
+
+
+def _watched_store():
+    from functools import partial
+
+    from karpenter_tpu.store import Store
+
+    store = Store()
+    watcher = _Watcher()
+    store.watch("Pod", watcher.on_event)
+    store.watch(None, partial(lambda tag, event, obj: None, "any"))
+    return store, watcher
+
+
+class TestStoreIngestCounters:
+    def test_write_adds_counters_and_no_span(self, fresh_tracer):
+        from karpenter_tpu.api.core import ObjectMeta, Pod
+
+        store, watcher = _watched_store()
+        with fresh_tracer.trace("reconcile.event_pass"):
+            store.create(Pod(metadata=ObjectMeta(name="p0")))
+        spans = fresh_tracer.snapshot()
+        assert [s["name"] for s in spans] == ["reconcile.event_pass"]
+        counters = spans[0]["args"]["counters"]
+        assert watcher.events == ["Added"]
+        assert set(counters) == {
+            "store.write.Pod", "store.notify.Pod",
+            "watch._Watcher.on_event",
+            "watch._watched_store.<locals>.<lambda>",  # a partial's function
+        }
+        assert all(c["count"] == 1 for c in counters.values())
+        assert (
+            counters["store.write.Pod"]["seconds"]
+            >= counters["store.notify.Pod"]["seconds"]
+            >= counters["watch._Watcher.on_event"]["seconds"]
+        )
+
+    def test_each_write_kind_counts(self, fresh_tracer):
+        from karpenter_tpu.api.core import ObjectMeta, Pod
+
+        store, watcher = _watched_store()
+        pod = store.create(Pod(metadata=ObjectMeta(name="p0")))
+        pod = store.update(pod)
+        store.patch_status(pod)
+        store.delete("Pod", pod.metadata.namespace, "p0")
+        with fresh_tracer.trace("reconcile.tick"):
+            pass
+        counters = fresh_tracer.snapshot()[0]["args"]["counters"]
+        assert counters["store.write.Pod"]["count"] == 4
+        assert counters["store.notify.Pod"]["count"] == 4
+        assert watcher.events == ["Added", "Modified", "Modified", "Deleted"]
+
+    def test_disabled_tracer_counts_no_writes(self, fresh_tracer, monkeypatch):
+        from karpenter_tpu.api.core import ObjectMeta, Pod
+        from karpenter_tpu.store import store as store_module
+
+        def no_clock():
+            raise AssertionError("a disabled tracer's write read the clock")
+
+        store, watcher = _watched_store()
+        fresh_tracer.enabled = False
+        with monkeypatch.context() as patched:
+            patched.setattr(store_module, "_clock", no_clock)
+            store.create(Pod(metadata=ObjectMeta(name="p0")))
+        fresh_tracer.enabled = True
+        with fresh_tracer.trace("reconcile.tick"):
+            pass
+        assert watcher.events == ["Added"]
+        assert "counters" not in fresh_tracer.snapshot()[0]["args"]
+
+    def test_one_counter_call_a_write(self, fresh_tracer, monkeypatch):
+        """The write's, the fan-out's and every watcher's counter go to
+        the tracer in one locked call."""
+        from karpenter_tpu.api.core import ObjectMeta, Pod
+
+        store, _ = _watched_store()
+        calls = []
+        add_all = fresh_tracer.add_all
+        monkeypatch.setattr(
+            fresh_tracer, "add_all",
+            lambda counts: (calls.append(list(counts)), add_all(counts)),
+        )
+        store.create(Pod(metadata=ObjectMeta(name="p0")))
+        assert len(calls) == 1
+        assert {name for name, _ in calls[0]} == {
+            "store.write.Pod", "store.notify.Pod",
+            "watch._Watcher.on_event",
+            "watch._watched_store.<locals>.<lambda>",
+        }
+
+    def test_ingest_and_scale_writes_count(self, fresh_tracer):
+        """apply_event (the apiserver informer's ingest) and update_scale
+        notify watchers, so they count like the CRUD writes."""
+        from karpenter_tpu.api.core import ObjectMeta, Pod
+        from karpenter_tpu.api.scalablenodegroup import ScalableNodeGroup
+        from karpenter_tpu.store.store import ADDED, DELETED
+
+        store, watcher = _watched_store()
+        pod = Pod(metadata=ObjectMeta(name="p0", resource_version=7))
+        store.apply_event(ADDED, pod)
+        store.apply_event(DELETED, pod)
+        group = store.create(ScalableNodeGroup(metadata=ObjectMeta(name="g")))
+        scale = store.get_scale(
+            "ScalableNodeGroup", group.metadata.namespace, "g"
+        )
+        scale.spec_replicas = 3
+        store.update_scale("ScalableNodeGroup", scale)
+        with fresh_tracer.trace("reconcile.tick"):
+            pass
+        counters = fresh_tracer.snapshot()[0]["args"]["counters"]
+        assert watcher.events == ["Added", "Deleted"]
+        assert counters["store.write.Pod"]["count"] == 2
+        assert counters["store.notify.Pod"]["count"] == 2
+        # the create and the scale write
+        assert counters["store.write.ScalableNodeGroup"]["count"] == 2
+
+
+class TestTickSpans:
+    @staticmethod
+    def _runtime():
+        from karpenter_tpu.api.core import ObjectMeta
+        from karpenter_tpu.api.metricsproducer import (
+            MetricsProducer, MetricsProducerSpec, PendingCapacitySpec,
+        )
+        from karpenter_tpu.cloudprovider.fake import FakeFactory
+        from karpenter_tpu.runtime import KarpenterRuntime, Options
+
+        runtime = KarpenterRuntime(
+            Options(), cloud_provider_factory=FakeFactory()
+        )
+        runtime.store.create(MetricsProducer(
+            metadata=ObjectMeta(name="pending"),
+            spec=MetricsProducerSpec(
+                pending_capacity=PendingCapacitySpec(
+                    node_selector={"pool": "a"},
+                )
+            ),
+        ))
+        return runtime
+
+    def test_tick_root_encloses_gauges_and_hook(self, fresh_tracer):
+        runtime = self._runtime()
+        try:
+            fresh_tracer.clear()
+            runtime.manager.reconcile_all()
+        finally:
+            runtime.close()
+        spans = fresh_tracer.snapshot()
+        root = next(s for s in spans if s["name"] == "reconcile.tick")
+        for name in ("solver.publish_gauges", "manager.tick_hook"):
+            child = next(s for s in spans if s["name"] == name)
+            assert child["parent"] == root["id"], name
+            assert root["ts_us"] <= child["ts_us"]
+            assert (child["ts_us"] + child["dur_us"]
+                    <= root["ts_us"] + root["dur_us"])
+
+    def test_tick_root_opens_once_the_pass_lock_is_held(self, fresh_tracer):
+        """tick_ms.tick reads the tick's work: a wait behind an event
+        pass holding the pass lock lies before the root span."""
+        runtime = self._runtime()
+        held, released = threading.Event(), []
+
+        def event_pass():
+            with runtime.manager._pass_lock:
+                held.set()
+                time.sleep(0.2)
+                released.append(time.perf_counter())
+
+        holder = threading.Thread(target=event_pass)
+        try:
+            holder.start()
+            assert held.wait(10)
+            fresh_tracer.clear()
+            runtime.manager.reconcile_all()
+        finally:
+            holder.join(10)
+            runtime.close()
+        root = next(
+            s for s in fresh_tracer.snapshot() if s["name"] == "reconcile.tick"
+        )
+        assert root["ts_us"] >= (released[0] - fresh_tracer._epoch) * 1e6
+
+    def test_solver_request_is_a_direct_child_of_mp_reconcile(
+        self, fresh_tracer
+    ):
+        """mp_reconcile_ms.tick subtracts exactly the solver.request
+        children of reconcile.MetricsProducer: the pendingcapacity.solve
+        label must not come between them."""
+        runtime = self._runtime()
+        try:
+            fresh_tracer.clear()
+            runtime.manager.converge(1)
+        finally:
+            runtime.close()
+        spans = fresh_tracer.snapshot()
+        mp = next(s for s in spans if s["name"] == "reconcile.MetricsProducer")
+        label = next(s for s in spans if s["name"] == "pendingcapacity.solve")
+        requests = [s for s in spans if s["name"] == "solver.request"]
+        assert requests
+        assert label["parent"] == mp["id"]
+        assert all(s["parent"] == mp["id"] for s in requests)
 
 
 # -- the end-to-end simulate pin ---------------------------------------------

@@ -1,14 +1,18 @@
-"""observability/profiler.py: the probe-once device-timeline hooks and
-the on-demand /debug/profile capture.
+"""observability/profiler.py and the tracer's profiler mirror: the
+reconcile tracer's spans entering a `jax.profiler.TraceAnnotation` while
+a capture records, and the on-demand /debug/profile capture.
 
-Pins (ISSUE 15 satellites):
+Pins:
 
-  * jax absent/broken -> `solver_trace` returns the SHARED no-op
-    annotation, and the probe result is CACHED (one import attempt per
-    process, not one per dispatch);
-  * with jax.profiler present the TraceAnnotation class is actually
-    used, and a broken annotation SETUP is swallowed while exceptions
-    from the traced block itself propagate;
+  * the profiler probe runs ONCE per process and is cached, available
+    or not;
+  * with no capture recording, spans enter no annotation, and a
+    disabled tracer hands out the SHARED no-op;
+  * while a capture records, context-managed spans (enabled tracer or
+    not) enter an annotation of their name; `begin()` spans do not;
+    annotation setup/teardown failures are swallowed while exceptions
+    from the traced block propagate;
+  * on a real capture the spans land on the trace's host plane;
   * `start_profiler_server` logs its failure reason instead of
     returning False silently;
   * `/debug/profile?ms=N` captures bounded + single-flight into the
@@ -16,6 +20,7 @@ Pins (ISSUE 15 satellites):
     id) and answers 503 when the probe failed or nothing is wired.
 """
 
+import glob
 import json
 import os
 import sys
@@ -25,86 +30,197 @@ import urllib.request
 import pytest
 
 from karpenter_tpu.metrics.registry import GaugeRegistry
-from karpenter_tpu.observability import MetricsServer
+from karpenter_tpu.observability import MetricsServer, Tracer
 from karpenter_tpu.observability import profiler as P
+from karpenter_tpu.observability import tracing as T
 
 
 @pytest.fixture
 def fresh_probe():
     """Reset the probe cache around each test (module-global state)."""
-    P.reset_probe()
+    T.reset_profiler_probe()
     yield
-    P.reset_probe()
+    T.reset_profiler_probe()
 
 
-class TestProbeOnce:
-    def test_broken_jax_profiler_yields_shared_noop(
+class FakeProfiler:
+    """A stand-in probe result: a switchable recording flag and an
+    annotation class that logs what it enters and exits."""
+
+    def __init__(self, recording=True, broken_setup=False,
+                 broken_teardown=False):
+        self.recording = recording
+        self.entered, self.exited = [], []
+        outer = self
+
+        class Annotation:
+            def __init__(self, name):
+                if broken_setup:
+                    raise RuntimeError("profiler backend fell over")
+                self.name = name
+
+            def __enter__(self):
+                outer.entered.append(self.name)
+                return self
+
+            def __exit__(self, *exc):
+                if broken_teardown:
+                    raise RuntimeError("profiler backend fell over")
+                outer.exited.append(self.name)
+                return False
+
+        self.probe = (lambda: self.recording, Annotation)
+
+
+@pytest.fixture
+def fake_profiler(fresh_probe, monkeypatch):
+    def install(**kwargs):
+        fake = FakeProfiler(**kwargs)
+        monkeypatch.setattr(T, "_PROFILER", fake.probe)
+        return fake
+
+    return install
+
+
+class TestProfilerMirror:
+    def test_broken_jax_profiler_probe_is_cached(
         self, fresh_probe, monkeypatch
     ):
         # sys.modules[name] = None makes `import jax.profiler` raise
         # ImportError — the "jax absent/broken" environment
         monkeypatch.setitem(sys.modules, "jax.profiler", None)
-        span = P.solver_trace("solver.dispatch")
-        assert span is P._NOOP_TRACE
-        # the probe is CACHED as unavailable: restoring the module does
-        # not resurrect annotations until reset_probe
+        tracer = Tracer()
+        tracer.enabled = False
+        assert tracer.span("solver.dispatch") is T._NOOP_SPAN
+        # cached as unavailable: restoring the module does not
+        # resurrect the mirror until the probe is reset
         monkeypatch.undo()
-        assert P._ANNOTATION_CLS is False
-        assert P.solver_trace("again") is P._NOOP_TRACE
-        # the no-op is a working context manager
-        with P.solver_trace("x"):
-            pass
+        assert T._PROFILER is False
+        assert not T.profiler_available()
+        assert tracer.trace("again") is T._NOOP_SPAN
 
-    def test_probe_caches_available_class(self, fresh_probe):
-        first = P.solver_trace("a")
-        assert isinstance(first, P._GuardedAnnotation)
-        cached = P._ANNOTATION_CLS
-        assert cached is not None and cached is not False
-        P.solver_trace("b")
-        assert P._ANNOTATION_CLS is cached  # no re-probe
+    def test_probe_runs_once(self, fresh_probe, monkeypatch):
+        calls = []
+        real = T._probe_profiler
 
-    def test_annotation_class_used_when_present(self, fresh_probe):
-        entered = []
+        def counting():
+            calls.append(1)
+            return real()
 
-        class FakeAnnotation:
-            def __init__(self, name):
-                entered.append(name)
+        monkeypatch.setattr(T, "_probe_profiler", counting)
+        tracer = Tracer()
+        for name in ("a", "b", "c"):
+            with tracer.span(name):
+                pass
+        assert len(calls) == 1
+        probed = T._PROFILER
+        assert probed is not None and probed is not False
+        # the recording check is TraceMe.is_enabled itself
+        from jax._src.lib import _profiler
 
-            def __enter__(self):
-                return self
+        assert probed[0] is _profiler.TraceMe.is_enabled
 
-            def __exit__(self, *exc):
-                return False
+    def test_no_annotation_while_not_recording(self, fake_profiler):
+        fake = fake_profiler(recording=False)
+        tracer = Tracer()
+        with tracer.trace("reconcile.tick"):
+            with tracer.span("solver.dispatch"):
+                pass
+        assert fake.entered == []
+        assert len(tracer.snapshot()) == 2
+        tracer.enabled = False
+        assert tracer.span("x") is tracer.trace("y") is T._NOOP_SPAN
 
-        P._ANNOTATION_CLS = FakeAnnotation
-        with P.solver_trace("solver.cost"):
-            pass
-        assert entered == ["solver.cost"]
+    def test_spans_enter_annotations_while_recording(self, fake_profiler):
+        fake = fake_profiler()
+        tracer = Tracer()
+        with tracer.trace("reconcile.tick"):
+            with tracer.span("solver.dispatch"):
+                assert fake.entered == ["reconcile.tick", "solver.dispatch"]
+            assert fake.exited == ["solver.dispatch"]
+        assert fake.exited == ["solver.dispatch", "reconcile.tick"]
+        assert [s["name"] for s in tracer.snapshot()] == [
+            "solver.dispatch", "reconcile.tick",
+        ]
 
-    def test_guarded_annotation_swallows_setup_failures(self):
-        class BrokenAnnotation:
-            def __init__(self, name):
-                raise RuntimeError("profiler backend fell over")
+    def test_disabled_tracer_still_mirrors(self, fake_profiler):
+        fake = fake_profiler()
+        tracer = Tracer()
+        tracer.enabled = False
+        with tracer.span("pendingcapacity.solve") as handle:
+            assert handle is None  # as the disabled tracer's no-op
+        assert fake.entered == fake.exited == ["pendingcapacity.solve"]
+        assert tracer.snapshot() == []
 
-        # setup failure is swallowed; the block still runs
+    def test_unstacked_span_mirrors(self, fake_profiler):
+        fake = fake_profiler()
+        tracer = Tracer()
+        with tracer.trace("reconcile.MetricsProducer") as root:
+            with tracer.span("pendingcapacity.solve", nest=False):
+                assert tracer.current() is root
+                request = tracer.begin("solver.request")
+            request.close()
+        assert fake.entered == [
+            "reconcile.MetricsProducer", "pendingcapacity.solve",
+        ]
+        assert request.parent_id == root.span_id
+
+    def test_begin_spans_not_mirrored(self, fake_profiler):
+        fake = fake_profiler()
+        tracer = Tracer()
+        handle = tracer.begin("solver.request")
+        handle.close(ok=True)
+        assert fake.entered == []
+        assert tracer.snapshot()[0]["name"] == "solver.request"
+
+    @pytest.mark.parametrize("broken", ["broken_setup", "broken_teardown"])
+    def test_annotation_failures_swallowed(self, fake_profiler, broken):
+        fake_profiler(**{broken: True})
+        tracer = Tracer()
         ran = []
-        with P._GuardedAnnotation(BrokenAnnotation, "x"):
+        with tracer.span("solver.cost"):
             ran.append(True)
         assert ran == [True]
-        # ...but an exception FROM the block propagates unchanged
-        class FineAnnotation:
-            def __init__(self, name):
-                pass
+        assert tracer.snapshot()[0]["args"] == {}
 
-            def __enter__(self):
-                return self
-
-            def __exit__(self, *exc):
-                return False
-
-        with pytest.raises(ValueError):
-            with P._GuardedAnnotation(FineAnnotation, "x"):
+    @pytest.mark.parametrize("enabled", [True, False])
+    def test_block_exceptions_propagate(self, fake_profiler, enabled):
+        fake = fake_profiler()
+        tracer = Tracer()
+        tracer.enabled = enabled
+        with pytest.raises(ValueError, match="the solve's own error"):
+            with tracer.span("solver.dispatch"):
                 raise ValueError("the solve's own error")
+        assert fake.exited == ["solver.dispatch"]
+        if enabled:
+            assert tracer.snapshot()[0]["args"]["error"] == "ValueError"
+
+    def test_spans_land_on_a_real_capture(self, fresh_probe, tmp_path):
+        import jax.profiler
+        from jax.profiler import ProfileData
+
+        tracer = Tracer()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracer.trace("reconcile.tick"):
+                with tracer.span("solver.publish_gauges"):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        with tracer.span("after.capture"):
+            pass
+        path = glob.glob(
+            str(tmp_path / "**" / "*.xplane.pb"), recursive=True
+        )[-1]
+        names = {
+            ev.name
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for ev in line.events
+        }
+        assert {"reconcile.tick", "solver.publish_gauges"} <= names
+        assert "after.capture" not in names
 
 
 class TestProfilerServer:

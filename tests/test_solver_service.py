@@ -304,6 +304,15 @@ class TestBackpressureAndDeadlines:
 
 
 class TestMetricsSurface:
+    @pytest.mark.parametrize("n", [1, 2, 7, 100, 256])
+    def test_stage_percentiles_match_numpy(self, n):
+        from karpenter_tpu.solver.service import _p50_p99
+
+        samples = list(np.random.default_rng(n).exponential(3.0, n))
+        assert _p50_p99(samples) == pytest.approx(
+            tuple(np.percentile(samples, (50, 99))), rel=1e-12
+        )
+
     def test_gauges_registered_and_published(self):
         registry = GaugeRegistry()
         svc = SolverService(registry=registry, window_s=0.0)

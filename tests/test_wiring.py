@@ -12,7 +12,7 @@ from karpenter_tpu.__main__ import main as cli_main
 from karpenter_tpu.__main__ import parse_args
 from karpenter_tpu.leaderelection import LeaderElector
 from karpenter_tpu.metrics.registry import GaugeRegistry
-from karpenter_tpu.observability import MetricsServer, solver_trace
+from karpenter_tpu.observability import MetricsServer
 from karpenter_tpu.store import Store
 
 
@@ -109,13 +109,6 @@ class TestMetricsEndpoint:
             server.stop()
 
 
-class TestSolverTrace:
-    def test_trace_is_transparent(self):
-        with solver_trace("binpack"):
-            x = 2 + 2
-        assert x == 4
-
-
 class TestCLI:
     def test_flag_defaults_match_reference(self):
         args = parse_args([])
@@ -185,11 +178,6 @@ class TestCLI:
 
 
 class TestObservabilityFixes:
-    def test_solver_trace_propagates_exceptions(self):
-        with pytest.raises(ValueError, match="the real error"):
-            with solver_trace("x"):
-                raise ValueError("the real error")
-
     def test_metrics_path_with_query_string(self):
         registry = GaugeRegistry()
         registry.register("queue", "length").set(
