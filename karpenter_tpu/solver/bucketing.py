@@ -176,112 +176,158 @@ def presence(inputs: BinPackInputs) -> Tuple[bool, ...]:
     )
 
 
-def _pad2(a, rows: int, cols: Optional[int] = None):
+def _pad2(a, rows: int, cols: Optional[int] = None,
+          out: Optional["PadStaging"] = None, name: str = ""):
     """Zero-pad a 1-D/2-D array up to (rows[, cols]); the same object is
     returned when no padding is needed so already-bucketed traffic (the
-    encoder's steady state) keeps identity-based device caches warm."""
+    encoder's steady state) keeps identity-based device caches warm.
+    With `out`, the padded array is the staging area's reused buffer for
+    operand `name`, zeroed beyond the array's extent as a fresh one is."""
     a = np.asarray(a)
-    if a.ndim == 1:
-        if a.shape[0] == rows:
-            return a
-        out = np.zeros(rows, a.dtype)
-        out[: a.shape[0]] = a
-        return out
-    if a.shape == (rows, cols):
+    shape = (rows,) if a.ndim == 1 else (rows, cols)
+    if a.shape == shape:
         return a
-    out = np.zeros((rows, cols), a.dtype)
-    out[: a.shape[0], : a.shape[1]] = a
-    return out
+    if out is None:
+        padded = np.zeros(shape, a.dtype)
+    else:
+        padded, stale = out.take(name, shape, a.dtype, a.shape)
+        for region in stale:
+            padded[region] = 0
+    padded[tuple(slice(0, n) for n in a.shape)] = a
+    return padded
 
 
-def pad_to_bucket(  # lint: allow-complexity — one presence guard per optional operand
-    inputs: BinPackInputs, shape: Tuple[int, int, int, int, int]
+def _beyond(last: Tuple[int, ...], now: Tuple[int, ...]) -> list:
+    """The part of extent `last` outside extent `now`, as disjoint index
+    regions, one for each axis on which `last` reaches further."""
+    regions = []
+    for axis, (was, new) in enumerate(zip(last, now)):
+        if was > new:
+            regions.append(
+                tuple(slice(0, min(w, n)) for w, n in zip(last[:axis], now[:axis]))
+                + (slice(new, was),)
+                + tuple(slice(0, w) for w in last[axis + 1:])
+            )
+    return regions
+
+
+class PadStaging:
+    """A host staging area that `pad_to_bucket(..., out=area)` pads into:
+    one buffer for each padded operand, allocated on first use and reused
+    by every later pad. Each buffer remembers the extent its last write
+    covered, so a smaller request after a larger one re-zeroes only what
+    the larger one wrote beyond the new extent: the padding holds zeros
+    because the area keeps it zero, not because the memory is new. A pad
+    into the area overwrites the arrays the previous pad returned, so
+    the caller must be done with them (copied to the device, the solve
+    waited on) before the next."""
+
+    __slots__ = ("_buffers",)
+
+    def __init__(self):
+        self._buffers: dict = {}  # name -> (buffer, extent last written)
+
+    def take(self, name: str, shape: tuple, dtype, extent: tuple):
+        """(the buffer for operand `name`, the regions of it the last
+        write reached beyond `extent`), recording `extent` as written."""
+        held = self._buffers.get(name)
+        if held is None or held[0].shape != shape or held[0].dtype != dtype:
+            buffer, stale = np.zeros(shape, dtype), []
+        else:
+            buffer, stale = held[0], _beyond(held[1], extent)
+        self._buffers[name] = (buffer, extent)
+        return buffer, stale
+
+
+# The padded axes of each operand: p pods, t groups, r resources, k
+# taints, l labels, and the constraint plane's c classes, s slots, d
+# domains. Every pad is zero (module docstring): extra rows are invalid
+# with zero weight, priority, claim and slot; extra groups have zero
+# allocatable, tier, reservation and domain; extra taint, label and
+# pack-class bits are unset; extra class, slot and domain columns are
+# inert (constraint_shape).
+_OPERAND_AXES = {
+    "pod_requests": "pr",
+    "pod_valid": "p",
+    "pod_intolerant": "pk",
+    "pod_required": "pl",
+    "group_allocatable": "tr",
+    "group_taints": "tk",
+    "group_labels": "tl",
+    "pod_weight": "p",
+    "pod_group_forbidden": "pt",
+    "pod_group_score": "pt",
+    "pod_exclusive": "p",
+    "pod_priority": "p",
+    "group_tier": "t",
+    "pod_claim": "p",
+    "group_reservation": "t",
+    "pod_pack_class": "pc",
+    "pod_spread_slot": "p",
+    "group_domain": "t",
+    "spread_cap": "sd",
+}
+
+
+def _padded_sizes(inputs: BinPackInputs, shape) -> dict:
+    p, t, r, k, l = shape
+    sizes = dict(p=p, t=t, r=r, k=k, l=l)
+    if inputs.pod_pack_class is not None:
+        sizes["c"] = bucket_up(inputs.pod_pack_class.shape[1], CLASS_FLOOR)
+    if inputs.spread_cap is not None:
+        sizes["s"] = bucket_up(inputs.spread_cap.shape[0], SLOT_FLOOR)
+        sizes["d"] = bucket_up(inputs.spread_cap.shape[1], DOMAIN_FLOOR)
+    return sizes
+
+
+def is_bucketed(inputs: BinPackInputs, shape) -> bool:
+    """Whether `inputs` already has the bucket `shape`, so that
+    pad_to_bucket hands them back as they are."""
+    sizes = _padded_sizes(inputs, shape)
+    return all(
+        getattr(inputs, name) is None
+        or tuple(getattr(inputs, name).shape)
+        == tuple(sizes[axis] for axis in _OPERAND_AXES[name])
+        for name in ("pod_requests", "group_allocatable", "pod_intolerant",
+                     "pod_required", "pod_pack_class", "spread_cap")
+    )
+
+
+def _dtype(a):
+    return a.dtype if hasattr(a, "dtype") else np.asarray(a).dtype
+
+
+def staging_key(inputs: BinPackInputs, shape) -> tuple:
+    """The PadStaging area `inputs` pad into at the bucket `shape`: one
+    for each bucket shape, presence tuple and set of operand dtypes.
+    The natural shape is left out, so sizes that jitter inside one rung
+    share one area."""
+    dtypes = tuple(
+        _dtype(a) for a in (getattr(inputs, n) for n in _OPERAND_AXES)
+        if a is not None
+    )
+    return (tuple(shape), presence(inputs), dtypes)
+
+
+def pad_to_bucket(
+    inputs: BinPackInputs, shape: Tuple[int, int, int, int, int],
+    out: Optional[PadStaging] = None,
 ) -> BinPackInputs:
     """Pad every operand to the bucket `shape` (see module docstring for
-    why this is exact). Returns `inputs` unchanged when already there."""
-    p, t, r, k, l = shape
-    pc = inputs.pod_pack_class
-    caps = inputs.spread_cap
-    c_pad = None if pc is None else bucket_up(pc.shape[1], CLASS_FLOOR)
-    s_pad = None if caps is None else bucket_up(caps.shape[0], SLOT_FLOOR)
-    d_pad = None if caps is None else bucket_up(caps.shape[1], DOMAIN_FLOOR)
-    if (
-        inputs.pod_requests.shape == (p, r)
-        and inputs.group_allocatable.shape == (t, r)
-        and inputs.pod_intolerant.shape == (p, k)
-        and inputs.pod_required.shape == (p, l)
-        and (pc is None or pc.shape == (p, c_pad))
-        and (caps is None or caps.shape == (s_pad, d_pad))
-    ):
+    why this is exact). Returns `inputs` unchanged when already there.
+    With `out`, the padded operands are that staging area's buffers, equal
+    element for element to the fresh arrays a pad without it returns, in
+    a new BinPackInputs on every call."""
+    if is_bucketed(inputs, shape):
         return inputs
-    # pod_weight: absent means "every row counts once", so padding an
-    # absent weight must materialize ones for real rows + zeros for pads
-    # (an all-ones pad would count invalid padding rows into nothing —
-    # they are valid=False — but zero weight keeps the aggregates exact
-    # even if a future stage forgets the validity mask)
-    weight = inputs.pod_weight
-    if weight is not None:
-        weight = _pad2(weight, p)
-    forbidden = inputs.pod_group_forbidden
-    if forbidden is not None:
-        forbidden = _pad2(forbidden, p, t)
-    score = inputs.pod_group_score
-    if score is not None:
-        score = _pad2(score, p, t)
-    exclusive = inputs.pod_exclusive
-    if exclusive is not None:
-        exclusive = _pad2(exclusive, p)
-    # priority pads at 0 (no steering) and tier at 0 (on-demand) — both
-    # only act on rows/columns that are valid/feasible anyway
-    priority = inputs.pod_priority
-    if priority is not None:
-        priority = _pad2(priority, p)
-    tier = inputs.group_tier
-    if tier is not None:
-        tier = _pad2(tier, t)
-    # constraint-plane operands: claim/slot pad 0 (unclaimed /
-    # unconstrained — their rows are invalid anyway), reservation/domain
-    # pad 0 on zero-allocatable groups nothing fits, pack-class rows pad
-    # all-false (invalid rows never reach a histogram) and class/slot/
-    # domain axes pad up their own ladders (inert — see
-    # constraint_shape)
-    claim = inputs.pod_claim
-    if claim is not None:
-        claim = _pad2(claim, p)
-    reservation = inputs.group_reservation
-    if reservation is not None:
-        reservation = _pad2(reservation, t)
-    if pc is not None:
-        pc = _pad2(pc, p, c_pad)
-    slot = inputs.pod_spread_slot
-    if slot is not None:
-        slot = _pad2(slot, p)
-    domain = inputs.group_domain
-    if domain is not None:
-        domain = _pad2(domain, t)
-    if caps is not None:
-        caps = _pad2(caps, s_pad, d_pad)
-    return BinPackInputs(
-        pod_requests=_pad2(inputs.pod_requests, p, r),
-        pod_valid=_pad2(inputs.pod_valid, p),
-        pod_intolerant=_pad2(inputs.pod_intolerant, p, k),
-        pod_required=_pad2(inputs.pod_required, p, l),
-        group_allocatable=_pad2(inputs.group_allocatable, t, r),
-        group_taints=_pad2(inputs.group_taints, t, k),
-        group_labels=_pad2(inputs.group_labels, t, l),
-        pod_weight=weight,
-        pod_group_forbidden=forbidden,
-        pod_group_score=score,
-        pod_exclusive=exclusive,
-        pod_priority=priority,
-        group_tier=tier,
-        pod_claim=claim,
-        group_reservation=reservation,
-        pod_pack_class=pc,
-        pod_spread_slot=slot,
-        group_domain=domain,
-        spread_cap=caps,
-    )
+    sizes = _padded_sizes(inputs, shape)
+    fields = {}
+    for name, axes in _OPERAND_AXES.items():
+        a = getattr(inputs, name)
+        if a is not None:
+            a = _pad2(a, *(sizes[axis] for axis in axes), out=out, name=name)
+        fields[name] = a
+    return BinPackInputs(**fields)
 
 
 # -- eviction-planning (ops/preempt.py) shape ladder --------------------------

@@ -106,15 +106,18 @@ from karpenter_tpu.ops.binpack import (
     has_constraint_operands,
 )
 from karpenter_tpu.solver.bucketing import (
+    PadStaging,
     bucket_up,
     bucket_shape,
     constraint_shape,
     crop_outputs,
     crop_preempt_outputs,
+    is_bucketed,
     pad_preempt_inputs,
     pad_to_bucket,
     preempt_bucket_shape,
     presence,
+    staging_key,
 )
 from karpenter_tpu.utils.log import logger
 
@@ -142,6 +145,9 @@ RESIDENT_BYTES = "resident_bytes"
 RESIDENT_ROWS = "resident_rows"
 RESIDENT_SCATTER_MS = "resident_scatter_ms"
 RESIDENT_REBUILDS = "resident_rebuilds_total"
+# the one-chip Mosaic path's pad into reused host staging areas
+# {name=reuse|alloc} (docs/solver-service.md "Shape-bucket ladder")
+PAD_STAGING = "pad_staging_total"
 # boot-time compile pre-warm (docs/solver-service.md "Compile pre-warm")
 PREWARM_COMPILES = "prewarm_compiles_total"
 PREWARM_MS = "prewarm_ms"
@@ -193,6 +199,12 @@ COMPILE_GRACE_S = 120.0
 
 _STAGE_WINDOW = 256  # per-stage latency ring size (fleet-scale constant)
 
+# Host staging areas the one-chip Mosaic path keeps (_staged_pad), least
+# recently used first out: three hold a fleet whose size jitters across
+# one rung boundary (two adjacent rungs) beside the MetricsProducer's
+# own small rung, at ~19 MB an area at the 131,072-row rung.
+_STAGING_AREAS = 3
+
 # native-histogram ladders (docs/observability.md): stage latencies run
 # from sub-ms host work to tens-of-seconds first compiles; coalesce
 # batch sizes follow the power-of-two batch ladder
@@ -206,6 +218,29 @@ _COALESCE_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
 # above it the full window holds so concurrent bursts keep coalescing.
 _LOAD_ALPHA = 0.5
 _LOAD_IDLE = 1.5
+
+
+def _keep_freed_heap() -> None:
+    """Pin glibc malloc's thresholds where its own dynamic rule leaves
+    them once a 32 MiB block is freed: blocks up to 32 MiB come from the
+    heap, and up to 64 MiB of freed heap stays mapped. A pad into fresh
+    arrays freed 8-19 MB blocks every call, which raised the thresholds
+    as a side effect; with the pad staged nothing does, they stay at
+    128 KiB, and a caller that builds fresh operands for every call (the
+    sidecar's decode) maps, faults and unmaps them every call: on a v5e
+    host a 100k-pod solve call then took 22.4 ms, against 16.4-17.0 ms
+    with the thresholds pinned. Nothing where the C library has no
+    mallopt."""
+    import ctypes
+
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (AttributeError, OSError):
+        return
+    mallopt.argtypes = (ctypes.c_int, ctypes.c_int)
+    mallopt.restype = ctypes.c_int
+    mallopt(-3, 32 << 20)  # M_MMAP_THRESHOLD
+    mallopt(-1, 64 << 20)  # M_TRIM_THRESHOLD
 
 
 def _p50_p99(samples) -> Tuple[float, float]:
@@ -300,6 +335,9 @@ class SolverStatistics:
     resident_scatters: int = 0  # dispatches served via a changed-row scatter
     resident_rebuilds: int = 0  # full uploads (re)establishing residency
     resident_drops: int = 0  # wholesale discards (ladder / recovery boot)
+    # the one-chip Mosaic path's pads into host staging areas
+    pad_staging_reuses: int = 0  # pads into an area an earlier call made
+    pad_staging_allocs: int = 0  # pads that had to make a new area
     pipeline_splits: int = 0  # lone batches chunked so the pipeline overlaps
     # backend health FSM + watchdog (docs/resilience.md)
     device_failures: int = 0  # total device-path failures (any rung)
@@ -527,6 +565,10 @@ class SolverService:
         # dispatched live only here and must be drained too
         self._current_batch: List[_Request] = []
         self._tls = threading.local()
+        # (owning worker generation, staging key -> PadStaging): the
+        # host areas _solve_pallas pads into, replaced whole for every
+        # new worker and by reset_caches
+        self._staging = self._fresh_staging()
         # per-tick dispatch accounting (note_tick): the gauge shows the
         # delta of stats.dispatches between manager ticks
         self._tick_dispatch_mark = 0
@@ -579,6 +621,7 @@ class SolverService:
         self._c_resident_rebuilds = reg(
             SUBSYSTEM, RESIDENT_REBUILDS, kind="counter"
         )
+        self._c_pad_staging = reg(SUBSYSTEM, PAD_STAGING, kind="counter")
         # boot-time pre-warm: rungs compiled {name=<family>} and the
         # wall cost of each family's warm dispatch — near-zero when the
         # persistent compile cache (JAX_COMPILATION_CACHE_DIR) served it
@@ -669,14 +712,15 @@ class SolverService:
         fleet decide) into the service's latency surface. With `span`,
         the block is also that tracer span (a child of `parent` when
         given, else of this thread's current span): the bin-pack's
-        host stages are timed at one boundary for both."""
+        host stages are timed at one boundary for both. Yields the open
+        span, None where there is none to annotate."""
         t0 = _time.perf_counter()
         try:
             if span is None:
-                yield
+                yield None
             else:
-                with default_tracer().span(span, parent=parent):
-                    yield
+                with default_tracer().span(span, parent=parent) as opened:
+                    yield opened
         finally:
             self._record_stage(stage, _time.perf_counter() - t0)
 
@@ -711,6 +755,9 @@ class SolverService:
         self._resident.drop_all()
         # a reset plane may legitimately want a fresh warm-up
         self._prewarmed = set()
+        # and the pad's host staging areas go with the arrays they held
+        with self._cond:
+            self._staging = self._fresh_staging()
 
     # -- introspection plane (observability/devicetelemetry.py) ------------
 
@@ -2117,8 +2164,11 @@ class SolverService:
     # -- worker -----------------------------------------------------------
 
     def _spawn_worker(self) -> None:
-        # called under self._cond
+        # called under self._cond; the new worker never pads into the
+        # staging areas of the one it replaces (a superseded worker may
+        # still be reading its own)
         self._worker_gen += 1
+        self._staging = self._fresh_staging()
         self._worker = threading.Thread(
             target=self._run, args=(self._worker_gen,),
             name="solver-service", daemon=True,
@@ -2764,10 +2814,16 @@ class SolverService:
         fresh = self._count_compile(cache_key)
         grace = COMPILE_GRACE_S if fresh else 0.0
         for request in live:
-            with self.track("pad", "solver.pad", request.span):
-                padded, kernel = B.fold_for_pallas(
-                    pad_to_bucket(request.inputs, shape)
+            # the pad reuses the previous request's host staging area:
+            # that request's transfer and solve were waited on
+            # (block_until_ready) and its result scattered before here
+            with self.track("pad", "solver.pad", request.span) as span:
+                staged_inputs, staged = self._staged_pad(
+                    request.inputs, shape
                 )
+                if span is not None:
+                    span.args["staged"] = staged
+                padded, kernel = B.fold_for_pallas(staged_inputs)
             t0 = _time.perf_counter()
             with self._dispatch_span("solver.dispatch", [request]):
                 with self._device_section([request], grace=grace):
@@ -2794,6 +2850,38 @@ class SolverService:
             with self.track("scatter", "solver.scatter", request.span):
                 result = self._crop_host(out, request)
             request.finish(result=result)
+
+    def _fresh_staging(self) -> tuple:
+        return (self._worker_gen, collections.OrderedDict())
+
+    def _staged_pad(self, inputs: BinPackInputs, shape):
+        """(inputs padded to `shape`, "reuse" | "alloc" | None): the
+        one-chip Mosaic path's pad, into a host staging area this worker
+        keeps for the staging key (bucketing.staging_key), at most
+        _STAGING_AREAS of them. None where nothing is staged: inputs
+        already at the bucket pass through, and a worker the watchdog
+        superseded pads into fresh arrays. Only this path stages: the
+        batched, sharded and pipelined paths hold several padded sets at
+        once, or free them after a dispatch they do not wait on."""
+        if is_bucketed(inputs, shape):
+            return inputs, None
+        owner, areas = self._staging
+        if owner != getattr(self._tls, "gen", None):
+            return pad_to_bucket(inputs, shape), None
+        key = staging_key(inputs, shape)
+        area = areas.pop(key, None)
+        if area is None:
+            area, staged = PadStaging(), "alloc"
+            self.stats.pad_staging_allocs += 1
+            _keep_freed_heap()
+            if len(areas) >= _STAGING_AREAS:
+                areas.popitem(last=False)
+        else:
+            staged = "reuse"
+            self.stats.pad_staging_reuses += 1
+        areas[key] = area
+        self._c_pad_staging.inc(staged, "-")
+        return pad_to_bucket(inputs, shape, out=area), staged
 
     def _begin_batched_xla(
         self, shape, buckets: int, live: List[_Request],
