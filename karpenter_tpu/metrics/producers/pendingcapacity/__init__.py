@@ -394,7 +394,10 @@ def _occupancy_census(store, feed, all_pods, nodes, snap):
     constraints or soft preferences pay for a census (freed arena slots
     are zeroed, so the id scan is exact); unconstrained fleets skip it
     entirely — and their encode memo stays insensitive to bound-pod
-    churn. Returns (census, namespace_state, needs_census)."""
+    churn. Returns (census, namespace_state, needs_census). The build is
+    the `pendingcapacity.census` span; its `refresh` arg says whether
+    bound-pod or node churn since the last solve makes this one's
+    queries recompute."""
     needs_census = any(
         ids is not None and bool((ids != 0).any())
         for ids in (
@@ -406,9 +409,12 @@ def _occupancy_census(store, feed, all_pods, nodes, snap):
     )
     if not needs_census:
         return None, (), False
-    if feed is None and all_pods is None:
-        all_pods = store.list("Pod")
-    census, namespace_state = _build_census(store, feed, all_pods, nodes)
+    with default_tracer().span("pendingcapacity.census") as span:
+        if feed is None and all_pods is None:
+            all_pods = store.list("Pod")
+        census, namespace_state = _build_census(store, feed, all_pods, nodes)
+        if span is not None:
+            span.args["refresh"] = census.stale()
     return census, namespace_state, True
 
 

@@ -56,13 +56,22 @@ class DomainCensus:
         self.published = 0
         self.evictions_published = 0
 
+    def stale(self) -> bool:
+        """Whether the next query recomputes: bound-pod or node churn
+        since the memos were filled (the `refresh` arg of the
+        `pendingcapacity.census` span)."""
+        return (self._occupancy.generation, self._node_version_fn()) != self._epoch
+
     def _fresh(self, generation: int) -> None:
         epoch = (generation, self._node_version_fn())
         if epoch != self._epoch:
+            if self._epoch is None or epoch[1] != self._epoch[1]:
+                # node-side work reads the nodes alone: bound-pod churn
+                # keeps it (a 5,000-node walk per churned solve otherwise)
+                self._node_memo.clear()
+                self._named_labels = None
             self._epoch = epoch
             self._memo.clear()
-            self._node_memo.clear()
-            self._named_labels = None
             self.refreshes += 1
 
     def _node_counts(self, namespace, sel_form) -> Dict[str, int]:
@@ -257,6 +266,31 @@ class DomainCensus:
                 if value is not None:
                     allowed[key].add(value)
         return allowed
+
+
+class TimedCensus:
+    """A DomainCensus seen through a stopwatch: every query method called
+    on it runs on the census and adds its seconds to `seconds` (the
+    `census.query` counter of the `pendingcapacity.expand` span). A
+    query the census makes of itself (domain_counts -> spread) counts
+    once, inside its caller."""
+
+    def __init__(self, census, clock):
+        self._census = census
+        self._clock = clock
+        self.seconds = 0.0
+
+    def __getattr__(self, name):
+        query = getattr(self._census, name)
+
+        def timed(*args, **kwargs):
+            t0 = self._clock()
+            try:
+                return query(*args, **kwargs)
+            finally:
+                self.seconds += self._clock() - t0
+
+        return timed
 
 
 def _row_node_filter(snap, slot: int) -> tuple:

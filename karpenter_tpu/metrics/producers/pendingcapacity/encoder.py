@@ -6,6 +6,7 @@ states is pinned by the oracle suites."""
 from __future__ import annotations
 
 import threading
+import time as _time
 import weakref
 from typing import Dict, List, Optional, Tuple
 
@@ -19,12 +20,14 @@ from karpenter_tpu.api.core import (
     matches_selector,
 )
 from karpenter_tpu.constraints import compiler as _cc
+from karpenter_tpu.observability import default_tracer
 from karpenter_tpu.ops import binpack as B
 from karpenter_tpu.resilience import CircuitBreaker
 from karpenter_tpu.store.columnar import RESOURCE_PODS
 from karpenter_tpu.utils.functional import pad_to_multiple
 
 from .anti import _expand_anti_rows
+from .census import TimedCensus
 from .constants import (
     DEFAULT_PODS_PER_NODE,
     GROUP_PAD,
@@ -38,6 +41,7 @@ from .scoring import _score_rows
 from .spread import _expand_spread_rows
 
 _pad = pad_to_multiple
+_clock = _time.perf_counter  # census.query's stopwatch
 
 # -- constraint plane (karpenter_tpu/constraints) ----------------------------
 # The declarative-constraint compile is gated by a circuit breaker plus
@@ -444,6 +448,61 @@ def _priority_tier_operands(snap, profiles, row_idx, n_pods, n_groups):
     return pod_priority, group_tier
 
 
+def _expand(snap, profiles, row_idx, row_weight, group_label_dicts, census):
+    """(row_idx, row_weight, forbidden-or-None, exclusive-or-None) after
+    the two row expansions."""
+    # hard topology spread: constrained rows split into balanced
+    # per-domain sub-rows (same source row gathered more than once, each
+    # chunk masked to its domain's groups) — the device program is
+    # unchanged, spread rides the existing forbidden-mask operand
+    row_idx, row_weight, spread_forbidden = _expand_spread_rows(
+        snap, profiles, row_idx, row_weight, group_label_dicts,
+        census=census,
+    )
+    # required self pod-(anti-)affinity: hostname rows flag the
+    # pod_exclusive operand, domain keys cap one replica per domain
+    # (further sub-row expansion; the spread mask rides through)
+    return _expand_anti_rows(
+        snap, profiles, row_idx, row_weight, spread_forbidden,
+        group_label_dicts, census=census,
+    )
+
+
+def _live(ids, row_idx) -> bool:
+    return ids is not None and bool((ids[row_idx] != 0).any())
+
+
+def _expand_traced(snap, profiles, row_idx, row_weight, group_label_dicts,
+                   census):
+    """_expand inside a `pendingcapacity.expand` span (args rows_in,
+    rows_out, exclusive_rows) where a spread or anti shape is live, its
+    census queries timed into the tracer's `census.query` counter.
+    Where none is live, or the tracer is off, nothing is opened and the
+    clock is not read."""
+    tracer = default_tracer()
+    if not (
+        tracer.enabled
+        and len(row_idx)
+        and (_live(snap.spread_id, row_idx) or _live(snap.anti_id, row_idx))
+    ):
+        return _expand(
+            snap, profiles, row_idx, row_weight, group_label_dicts, census
+        )
+    timed = None if census is None else TimedCensus(census, _clock)
+    with tracer.span("pendingcapacity.expand", rows_in=len(row_idx)) as span:
+        out = _expand(
+            snap, profiles, row_idx, row_weight, group_label_dicts,
+            census if timed is None else timed,
+        )
+        span.args["rows_out"] = len(out[0])
+        span.args["exclusive_rows"] = (
+            0 if out[3] is None else int(out[3].sum())
+        )
+    if timed is not None:
+        tracer.add("census.query", timed.seconds)
+    return out
+
+
 def _encode_full(  # lint: allow-complexity — the encode spine: one arm per optional operand family (priority/tier/spread/constraints)
     snap, profiles, with_rows: bool = False, census=None, constraints=None
 ):
@@ -487,21 +546,9 @@ def _encode_full(  # lint: allow-complexity — the encode spine: one arm per op
         row_idx, row_weight = _dedup_rows_constrained(snap, membership)
     else:
         row_idx, row_weight = _dedup_rows(snap)
-    # hard topology spread: constrained rows split into balanced
-    # per-domain sub-rows (same source row gathered more than once, each
-    # chunk masked to its domain's groups) — the device program is
-    # unchanged, spread rides the existing forbidden-mask operand
-    row_idx, row_weight, spread_forbidden = _expand_spread_rows(
-        snap, profiles, row_idx, row_weight, group_label_dicts,
-        census=census,
-    )
-    # required self pod-(anti-)affinity: hostname rows flag the
-    # pod_exclusive operand, domain keys cap one replica per domain
-    # (further sub-row expansion; the spread mask rides through)
     row_idx, row_weight, spread_forbidden, row_exclusive = (
-        _expand_anti_rows(
-            snap, profiles, row_idx, row_weight, spread_forbidden,
-            group_label_dicts, census=census,
+        _expand_traced(
+            snap, profiles, row_idx, row_weight, group_label_dicts, census
         )
     )
 

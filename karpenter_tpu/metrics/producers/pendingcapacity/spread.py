@@ -172,9 +172,11 @@ def _split_entry_caps(e, values, counts_e, present_e, frozen):
 
 
 def _spread_state(namespace, entries, values, census, row_filter,
-                  label_dicts, eligible, extra_dead=None):
+                  label_dicts, eligible, split_groups, extra_dead=None):
     """IMMUTABLE per-(shape, node-filter) cap VIEW — what the
-    scheduler's skew checks admit for a row carrying this filter:
+    scheduler's skew checks admit for a row carrying this filter
+    (`split_groups`: each split-key value's eligible groups, the
+    caller's walk of the groups):
 
     - `static`[d]: split-key caps from non-selfMatch entries (0 or
       unbounded — placements never consume them);
@@ -222,9 +224,6 @@ def _spread_state(namespace, entries, values, census, row_filter,
     )
     has_other_partitions = bool(others)
     seed_covers = _seed_covers(entries, split_key)
-    split_groups: Dict[str, list] = {}
-    for t in eligible:
-        split_groups.setdefault(label_dicts[t][split_key], []).append(t)
     frozen = _frozen_split_values(values, split_groups, dead)
     for entry_idx, e in enumerate(entries):
         if e[0] != split_key:
@@ -397,27 +396,33 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
             return row_idx, row_weight, None
 
     # per live shape: (namespace, entries, ordered domain values,
-    # [D, T] per-domain forbidden-mask matrix — built ONCE per shape,
-    # rows are emitted by reference and only copied by the final stack)
+    # [D, T] per-domain forbidden-mask matrix, eligible groups, split
+    # value -> its groups). The walk of the groups depends on the keys
+    # alone, so shapes keyed alike (every zone-spread workload) share
+    # one walk and one read-only mask matrix: rows are emitted by
+    # reference and only copied by the final stack
     plan: Dict[int, tuple] = {}
+    walks: Dict[tuple, tuple] = {}
     for s in np.unique(live_ids):
         shape = shapes[s]
         if not shape:
             continue
         namespace, entries = shape
-        keys = [entry[0] for entry in entries]
-        split_key = entries[0][0]
-        domains: Dict[str, list] = {}
-        eligible = []
-        for t, labels in enumerate(label_dicts):
-            if all(key in labels for key in keys):
-                eligible.append(t)
-                domains.setdefault(labels[split_key], []).append(t)
-        values = sorted(domains)
-        masks = np.ones((len(values), n_groups), bool)
-        for rank, value in enumerate(values):
-            masks[rank, domains[value]] = False
-        plan[int(s)] = (namespace, entries, values, masks, eligible)
+        keys = tuple(entry[0] for entry in entries)
+        walk = walks.get(keys)
+        if walk is None:
+            domains: Dict[str, list] = {}
+            eligible = []
+            for t, labels in enumerate(label_dicts):
+                if all(key in labels for key in keys):
+                    eligible.append(t)
+                    domains.setdefault(labels[keys[0]], []).append(t)
+            values = sorted(domains)
+            masks = np.ones((len(values), n_groups), bool)
+            for rank, value in enumerate(values):
+                masks[rank, domains[value]] = False
+            walk = walks[keys] = (values, masks, eligible, domains)
+        plan[int(s)] = (namespace, entries, *walk)
 
     all_forbidden = np.ones(n_groups, bool)
     no_forbidden = np.zeros(n_groups, bool)
@@ -460,7 +465,7 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
             out_weight.append(row_weight[i])
             out_forbidden.append(no_forbidden)
             continue
-        namespace, entries, values, masks, eligible = entry
+        namespace, entries, values, masks, eligible, domains = entry
         weight = int(row_weight[i])
         if not values or weight == 0:
             # no group exposes the key(s): unschedulable by spread —
@@ -509,7 +514,7 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
         if view is None:
             view = _spread_state(
                 namespace, entries, values, census, row_filter,
-                label_dicts, eligible, extra_dead=anti_dead,
+                label_dicts, eligible, domains, extra_dead=anti_dead,
             )
             view_memo[view_key] = view
         ledger = ledgers.get(int(sid))

@@ -1070,6 +1070,135 @@ class TestTickSpans:
         assert all(s["parent"] == mp["id"] for s in requests)
 
 
+def _fleet_store(constrained: bool):
+    """Three one-node groups in three zones; a Deployment of four pending
+    pods (with a required zone spread when `constrained`) and one bound
+    replica in zone a; a StatefulSet of two pending pods (with required
+    hostname self anti-affinity when `constrained`)."""
+    from karpenter_tpu.api.core import (
+        Affinity, Container, LabelSelector, Node, NodeCondition, NodeStatus,
+        ObjectMeta, Pod, PodAffinityTerm, PodAntiAffinity, PodSpec,
+        TopologySpreadConstraint,
+    )
+    from karpenter_tpu.api.metricsproducer import (
+        MetricsProducer, MetricsProducerSpec, PendingCapacitySpec,
+    )
+    from karpenter_tpu.store import Store
+    from karpenter_tpu.utils.quantity import Quantity
+
+    zone = "topology.kubernetes.io/zone"
+    store = Store()
+    for z in "abc":
+        store.create(Node(
+            metadata=ObjectMeta(name=f"n-{z}", labels={"group": z, zone: f"zone-{z}"}),
+            status=NodeStatus(
+                allocatable={"cpu": Quantity.parse("8"), "memory": Quantity.parse("32Gi")},
+                conditions=[NodeCondition(type="Ready", status="True")],
+            ),
+        ))
+        store.create(MetricsProducer(
+            metadata=ObjectMeta(name=f"mp-{z}"),
+            spec=MetricsProducerSpec(pending_capacity=PendingCapacitySpec(node_selector={"group": z})),
+        ))
+    spread = [TopologySpreadConstraint(
+        max_skew=1, topology_key=zone, when_unsatisfiable="DoNotSchedule",
+        label_selector={"matchLabels": {"app": "web"}})] if constrained else []
+    anti = Affinity(pod_anti_affinity=PodAntiAffinity(
+        required_during_scheduling_ignored_during_execution=[PodAffinityTerm(
+            label_selector=LabelSelector(match_labels={"app": "db"}),
+            topology_key="kubernetes.io/hostname")])) if constrained else None
+
+    def pod(name, labels, node="", **spec):
+        return Pod(metadata=ObjectMeta(name=name, labels=labels), spec=PodSpec(
+            node_name=node, containers=[Container(requests={
+                "cpu": Quantity.parse("1"), "memory": Quantity.parse("1Gi")})], **spec))
+
+    store.create(pod("web-old", {"app": "web"}, node="n-a"))
+    for i in range(4):
+        store.create(pod(f"web-{i}", {"app": "web"}, topology_spread_constraints=spread))
+    for i in range(2):
+        store.create(pod(f"db-{i}", {"app": "db", "statefulset.kubernetes.io/pod-name": f"db-{i}"},
+                         affinity=anti))
+    return store, pod
+
+
+class TestConstrainedEncodeSpans:
+    """The census build and the spread/anti row expansion have spans, and
+    the census queries inside the expansion a counter; an unconstrained
+    fleet opens none of them (census_ms.constrained and
+    expand_ms.constrained read these)."""
+
+    @staticmethod
+    def _solve(tracer, store, feed):
+        from karpenter_tpu.metrics.producers.pendingcapacity import solve_pending
+
+        with tracer.trace("reconcile.tick"):
+            solve_pending(store, [], GaugeRegistry(), feed=feed)
+
+    @staticmethod
+    def _feed(store):
+        from karpenter_tpu.metrics.producers.pendingcapacity import group_profile
+        from karpenter_tpu.store.columnar import PendingFeed
+
+        return PendingFeed(store, group_profile)
+
+    def test_constrained_encode_opens_both_spans_and_counts_queries(self, fresh_tracer):
+        store, pod = _fleet_store(constrained=True)
+        feed = self._feed(store)
+        self._solve(fresh_tracer, store, feed)
+        spans = {s["name"]: s for s in fresh_tracer.snapshot()}
+        root = spans["reconcile.tick"]
+        census, expand = spans["pendingcapacity.census"], spans["pendingcapacity.expand"]
+        assert census["parent"] == expand["parent"] == root["id"]
+        assert census["args"]["refresh"] is True
+        # web and db dedup to one row each; web splits by zone, db is exclusive
+        assert expand["args"]["rows_in"] == 2
+        assert expand["args"]["rows_out"] > 2
+        assert expand["args"]["exclusive_rows"] == 1
+        query = root["args"]["counters"]["census.query"]
+        assert query["count"] == 1
+        assert 0 < query["seconds"] * 1e6 <= expand["dur_us"]
+
+        # nothing moved: the census build still runs, the encode memo holds
+        fresh_tracer.clear()
+        self._solve(fresh_tracer, store, feed)
+        names = [s["name"] for s in fresh_tracer.snapshot()]
+        assert "pendingcapacity.expand" not in names
+        census = next(s for s in fresh_tracer.snapshot() if s["name"] == "pendingcapacity.census")
+        assert census["args"]["refresh"] is False
+
+        # a bound replica moves: the census recomputes
+        fresh_tracer.clear()
+        store.delete("Pod", "default", "web-old")
+        store.create(pod("web-moved", {"app": "web"}, node="n-b"))
+        self._solve(fresh_tracer, store, feed)
+        spans = {s["name"]: s for s in fresh_tracer.snapshot()}
+        assert spans["pendingcapacity.census"]["args"]["refresh"] is True
+        assert "pendingcapacity.expand" in spans
+
+    def test_unconstrained_encode_opens_neither(self, fresh_tracer):
+        store, _ = _fleet_store(constrained=False)
+        self._solve(fresh_tracer, store, self._feed(store))
+        spans = fresh_tracer.snapshot()
+        names = {s["name"] for s in spans}
+        assert not names & {"pendingcapacity.census", "pendingcapacity.expand"}
+        assert "census.query" not in spans[-1]["args"].get("counters", {})
+
+    def test_disabled_tracer_reads_no_clock(self, fresh_tracer, monkeypatch):
+        from karpenter_tpu.metrics.producers.pendingcapacity import encoder
+
+        def no_clock():
+            raise AssertionError("a disabled tracer's expansion read the clock")
+
+        store, _ = _fleet_store(constrained=True)
+        feed = self._feed(store)
+        fresh_tracer.enabled = False
+        monkeypatch.setattr(encoder, "_clock", no_clock)
+        self._solve(fresh_tracer, store, feed)
+        assert fresh_tracer.snapshot() == []
+        assert feed.encode_memo is not None  # the constrained encode ran
+
+
 # -- the end-to-end simulate pin ---------------------------------------------
 
 

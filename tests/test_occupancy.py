@@ -390,6 +390,65 @@ class TestScheduledOccupancy:
                 assert live == detached
 
 
+class TestDomainCensusEpochs:
+    """DomainCensus recomputes pod-side answers on bound-pod churn and
+    node-side ones (the walk of the nodes' labels) on node churn only."""
+
+    def _census(self):
+        from karpenter_tpu.metrics.producers.pendingcapacity import (
+            DomainCensus,
+        )
+        from karpenter_tpu.store.columnar import ScheduledOccupancy
+        from karpenter_tpu.store.store import Store
+
+        store = Store()
+        nodes = [ready_node(f"n-{z}", {ZONE_KEY: f"us-{z}"}) for z in "ab"]
+        walks, version = [], [0]
+
+        def nodes_fn():
+            walks.append(1)
+            return nodes
+
+        census = DomainCensus(
+            ScheduledOccupancy(store), nodes_fn, lambda: version[0]
+        )
+        return store, census, nodes, walks, version
+
+    def test_bound_pod_churn_keeps_the_node_walk(self):
+        store, census, _, walks, _ = self._census()
+        sel = ((("app", "web"),), ())
+
+        def spread():
+            return census.spread(
+                "default", sel, ZONE_KEY, ("ignore",), lambda labels: True
+            )
+
+        store.create(bound_pod("p0", {"app": "web"}, "n-a"))
+        assert spread() == ({"us-a": 1}, {"us-a", "us-b"})
+        store.create(bound_pod("p1", {"app": "web"}, "n-b"))
+        assert census.stale()
+        assert spread() == ({"us-a": 1, "us-b": 1}, {"us-a", "us-b"})
+        assert not census.stale()
+        assert len(walks) == 1  # the pods moved, the nodes did not
+        assert census.refreshes == 2
+
+    def test_node_churn_walks_the_nodes_again(self):
+        store, census, nodes, walks, version = self._census()
+        sel = ((("app", "web"),), ())
+        store.create(bound_pod("p0", {"app": "web"}, "n-c"))
+        counts, present = census.spread(
+            "default", sel, ZONE_KEY, ("ignore",), lambda labels: True
+        )
+        assert (counts, present) == ({}, {"us-a", "us-b"})
+        nodes.append(ready_node("n-c", {ZONE_KEY: "us-c"}))
+        version[0] += 1
+        counts, present = census.spread(
+            "default", sel, ZONE_KEY, ("ignore",), lambda labels: True
+        )
+        assert (counts, present) == ({"us-c": 1}, {"us-a", "us-b", "us-c"})
+        assert len(walks) == 2
+
+
 class TestSpreadOccupancy:
     """Water-filled spread splits against existing per-domain counts."""
 
