@@ -380,13 +380,19 @@ def _pods_snapshot(store, feed, pod_cache):
     caches snapshot their watch-maintained arenas; the oracle path runs
     the same detached encoder over a fresh store.list — no drift
     possible. Returns (snapshot, all_pods) — all_pods is only non-None
-    on the oracle path, where the census can reuse the listing."""
-    if feed is not None:
-        return feed.pods.snapshot(), None
-    if pod_cache is not None:
-        return pod_cache.snapshot(), None
-    all_pods = store.list("Pod")
-    return snapshot_from_pods(all_pods), all_pods
+    on the oracle path, where the census can reuse the listing. A cache
+    read is the `pendingcapacity.snapshot` span; its `compacted` arg
+    says whether the read compacted the arena first."""
+    cache = feed.pods if feed is not None else pod_cache
+    if cache is None:
+        all_pods = store.list("Pod")
+        return snapshot_from_pods(all_pods), all_pods
+    with default_tracer().span("pendingcapacity.snapshot") as span:
+        compactions = cache.compactions
+        snap = cache.snapshot()
+        if span is not None:
+            span.args["compacted"] = cache.compactions != compactions
+    return snap, None
 
 
 def _occupancy_census(store, feed, all_pods, nodes, snap):

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time as _time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -60,6 +61,7 @@ from karpenter_tpu.api.core import (
 from karpenter_tpu.api.core import soft_spread_shape as _soft_spread_shape
 from karpenter_tpu.api.core import spread_shape as _spread_shape
 from karpenter_tpu.api.core import selector_form_matches
+from karpenter_tpu.observability.tracing import default_tracer
 from karpenter_tpu.store.store import DELETED, Store
 
 # seed columns; extended resources append after in arrival order.
@@ -87,6 +89,16 @@ def _intern(shapes: List[tuple], index: Dict[tuple, int], shape: tuple) -> int:
         index[shape] = sid
         shapes.append(shape)
     return sid
+
+
+def _bump(counts: dict, key, delta: int) -> None:
+    """Live-count update: `key` leaves `counts` when its count reaches
+    zero, so len(counts) is the number of distinct live keys."""
+    count = counts.get(key, 0) + delta
+    if count:
+        counts[key] = count
+    else:
+        del counts[key]
 
 
 def _adopt_and_watch(store: Store, kind: str, on_event) -> None:
@@ -141,6 +153,7 @@ class PendingPodCache:
         # on it to skip re-encoding + re-transferring an unchanged fleet
         self._generation = 0
         self._snap_memo: Optional[Tuple[int, "PendingSnapshot"]] = None
+        self.compactions = 0  # _compact() calls, ever
         self._reset_arena(max(16, capacity))
 
         if store is not None:
@@ -189,6 +202,15 @@ class PendingPodCache:
         # churned 100k-pod tick (~60 ms of argsort).
         self._dedup_slots: Dict[tuple, set] = {}
         self._slot_key: Dict[int, tuple] = {}
+        # live counts for the compaction check, kept at write time: per
+        # id column of _id_registries (same order), interned id -> live
+        # slots holding it, and per required-label item -> live slots
+        # selecting it. A key leaves its dict with its last slot, so
+        # len() is the live-distinct count _needs_compaction compares.
+        self._live_ids: Tuple[Dict[int, int], ...] = tuple(
+            {} for _ in range(8)
+        )
+        self._live_labels: Dict[Tuple[str, str], int] = {}
 
         self._requests = np.zeros(
             (capacity, len(self._resources) + 4), np.float32
@@ -225,6 +247,7 @@ class PendingPodCache:
         if slot is None:
             return
         self._generation += 1
+        self._count_live(slot, -1)
         self._valid[slot] = False
         self._requests[slot, :] = 0.0
         self._required[slot, :] = False
@@ -303,6 +326,8 @@ class PendingPodCache:
         if slot is None:
             slot = self._alloc()
             self._slot[key] = slot
+        else:
+            self._count_live(slot, -1)  # the previous encoding leaves
         self._generation += 1
         self._encode(slot, sparse)
 
@@ -352,6 +377,7 @@ class PendingPodCache:
         self._priority[slot] = sparse.priority
         self._valid[slot] = True
         self._sparse[slot] = sparse
+        self._count_live(slot, 1)
         # dedup maintenance: two slots share a key iff their canonical
         # sparse encodings match, which (with stable universe columns)
         # guarantees identical arena rows. Resource order in `requests` is
@@ -378,14 +404,10 @@ class PendingPodCache:
 
     # -- compaction --------------------------------------------------------
 
-    def _needs_compaction(self) -> bool:
-        """O(1) unless a cheap precondition trips: the O(live) live-set
-        scans below only run when a universe has already crossed the
-        absolute floor — snapshot() on a healthy cache stays a bulk copy."""
-        live = len(self._slot)
-        if self._hi >= _COMPACT_FLOOR and self._hi > _COMPACT_FACTOR * live:
-            return True
-        for registry, ids in (
+    def _id_registries(self) -> tuple:
+        """(registry, id column) for every interned id column, in
+        _live_ids order. Built per call: _alloc replaces grown columns."""
+        return (
             (self._shapes, self._shape_id),
             (self._affinity_shapes, self._affinity_id),
             (self._preferred_shapes, self._preferred_id),
@@ -394,25 +416,42 @@ class PendingPodCache:
             (self._soft_spread_shapes, self._soft_spread_id),
             (self._soft_anti_shapes, self._soft_anti_id),
             (self._label_sets, self._labels_id),
-        ):
-            if len(registry) >= _COMPACT_FLOOR:
-                live_ids = len(
-                    {int(ids[s]) for s in self._slot.values()}
-                )
-                if len(registry) > _COMPACT_FACTOR * max(1, live_ids):
-                    return True
-        if len(self._labels) >= _COMPACT_FLOOR:
-            live_labels: set = set()
-            for sparse in self._sparse.values():
-                live_labels.update(sparse.selector)
-            if len(self._labels) > _COMPACT_FACTOR * max(1, len(live_labels)):
-                return True
-        return False
+        )
+
+    def _count_live(self, slot: int, delta: int) -> None:
+        """Add (+1) or drop (-1) the encoded live slot's ids and required
+        label items in the live counts."""
+        for (_, ids), counts in zip(self._id_registries(), self._live_ids):
+            _bump(counts, ids.item(slot), delta)
+        for item in self._sparse[slot].selector:
+            _bump(self._live_labels, item, delta)
+
+    def _needs_compaction(self) -> bool:
+        """O(1): compares each universe's size with its live-distinct
+        count, kept at write time (_count_live), so snapshot() pays no
+        per-slot scan however many workloads or label sets are live.
+        Id 0 counts as a live id whenever a live slot holds it."""
+        live = len(self._slot)
+        if self._hi >= _COMPACT_FLOOR and self._hi > _COMPACT_FACTOR * live:
+            return True
+        sizes = [
+            (len(registry), len(counts))
+            for (registry, _), counts in zip(
+                self._id_registries(), self._live_ids
+            )
+        ]
+        sizes.append((len(self._labels), len(self._live_labels)))
+        return any(
+            size >= _COMPACT_FLOOR and size > _COMPACT_FACTOR * max(1, live_ids)
+            for size, live_ids in sizes
+        )
 
     def _compact(self) -> None:
         """Rebuild arenas + universes from live sparse records: O(live),
         restoring cost proportional to the live pending set after a peak
-        (incident) has drained or per-job universes have churned."""
+        (incident) has drained or per-job universes have churned. Each
+        one adds to the tracer's `pending_cache.compact` counter."""
+        start = _time.perf_counter()
         records = [
             (key, self._sparse[slot]) for key, slot in self._slot.items()
         ]
@@ -425,6 +464,10 @@ class PendingPodCache:
             slot = self._alloc()
             self._slot[key] = slot
             self._encode(slot, sparse)
+        self.compactions += 1
+        default_tracer().add(
+            "pending_cache.compact", _time.perf_counter() - start
+        )
 
     # -- arena management --------------------------------------------------
 

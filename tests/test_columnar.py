@@ -28,7 +28,11 @@ from karpenter_tpu.api.metricsproducer import (
 from karpenter_tpu.metrics.producers.pendingcapacity import solve_pending
 from karpenter_tpu.metrics.registry import GaugeRegistry
 from karpenter_tpu.store import Store
-from karpenter_tpu.store.columnar import PendingPodCache
+from karpenter_tpu.store.columnar import (
+    _COMPACT_FACTOR,
+    _COMPACT_FLOOR,
+    PendingPodCache,
+)
 from karpenter_tpu.utils.quantity import Quantity
 
 
@@ -236,6 +240,233 @@ class TestCompaction:
         oracle, cached = solve_both(store, cache)
         assert oracle == cached
         assert cached["small"][0] == 20
+
+
+# -- compaction check: live-id counts kept at write time ---------------------
+
+ZONE = "topology.kubernetes.io/zone"
+
+
+def _scan_live(cache):
+    """The O(live) scans the compaction check used to run, kept here as
+    the oracle: per id column, interned id -> live slots holding it, and
+    required-label item -> live slots selecting it."""
+    from collections import Counter
+
+    live = list(cache._slot.values())
+    columns = (
+        cache._shape_id, cache._affinity_id, cache._preferred_id,
+        cache._spread_id, cache._anti_id, cache._soft_spread_id,
+        cache._soft_anti_id, cache._labels_id,
+    )
+    ids = [dict(Counter(int(col[s]) for s in live)) for col in columns]
+    labels = dict(Counter(
+        item for s in live for item in cache._sparse[s].selector
+    ))
+    return ids, labels
+
+
+def _scan_needs_compaction(cache):
+    """The scan's compaction answer, as the cache gave it before its
+    live counts were kept at write time."""
+    live = len(cache._slot)
+    if cache._hi >= _COMPACT_FLOOR and cache._hi > _COMPACT_FACTOR * live:
+        return True
+    for registry, ids in (
+        (cache._shapes, cache._shape_id),
+        (cache._affinity_shapes, cache._affinity_id),
+        (cache._preferred_shapes, cache._preferred_id),
+        (cache._spread_shapes, cache._spread_id),
+        (cache._anti_shapes, cache._anti_id),
+        (cache._soft_spread_shapes, cache._soft_spread_id),
+        (cache._soft_anti_shapes, cache._soft_anti_id),
+        (cache._label_sets, cache._labels_id),
+    ):
+        if len(registry) >= _COMPACT_FLOOR:
+            live_ids = len({int(ids[s]) for s in cache._slot.values()})
+            if len(registry) > _COMPACT_FACTOR * max(1, live_ids):
+                return True
+    if len(cache._labels) >= _COMPACT_FLOOR:
+        live_labels = set()
+        for sparse in cache._sparse.values():
+            live_labels.update(sparse.selector)
+        if len(cache._labels) > _COMPACT_FACTOR * max(1, len(live_labels)):
+            return True
+    return False
+
+
+def constrained_pod(name, rng, step, hot):
+    """A pending pod whose every interned field is absent or one of a
+    few shared values, except in the `hot` registry (a _live_ids column
+    name, or "selector" for the label universe), which mostly draws a
+    value of its own: its ids churn away while the others stay put."""
+    from karpenter_tpu.api.core import (
+        Affinity,
+        LabelSelector,
+        NodeAffinity,
+        NodeSelector,
+        NodeSelectorRequirement,
+        NodeSelectorTerm,
+        PodAffinityTerm,
+        PodAntiAffinity,
+        PreferredSchedulingTerm,
+        TopologySpreadConstraint,
+        WeightedPodAffinityTerm,
+    )
+
+    def pick(kind):
+        draw = rng.random()
+        if draw < 0.2:
+            return None
+        if kind == hot and draw < 0.8:
+            return f"{kind}-{step}"
+        return f"{kind}-{rng.integers(3)}"
+
+    def term(zone):
+        return NodeSelectorTerm(match_expressions=[
+            NodeSelectorRequirement(key="zone", operator="In", values=[zone])
+        ])
+
+    def own(kind):  # self-matching, so the self slice keeps the term
+        return LabelSelector(match_labels={"app": app}, match_expressions=[
+            NodeSelectorRequirement(key="app", operator="In", values=[app, pick(kind) or ""])
+        ])
+
+    p = pod(name, cpu=str(rng.choice(["100m", "1"])))
+    app = pick("labels") or "web"
+    p.metadata.labels = {"app": app}
+    if hot == "labels" and rng.random() < 0.3:  # a StatefulSet pod's own label
+        p.metadata.labels["statefulset.kubernetes.io/pod-name"] = name
+    selector, toleration = pick("selector"), pick("shape")
+    if selector is not None:
+        p.spec.node_selector = {"disk": selector}
+    if toleration is not None:
+        p.spec.tolerations = [Toleration(key=toleration, operator="Exists")]
+    required, preferred = pick("affinity"), pick("preferred")
+    node_affinity = NodeAffinity(
+        required_during_scheduling_ignored_during_execution=(
+            None if required is None
+            else NodeSelector(node_selector_terms=[term(required)])
+        ),
+        preferred_during_scheduling_ignored_during_execution=(
+            [] if preferred is None
+            else [PreferredSchedulingTerm(weight=5, preference=term(preferred))]
+        ),
+    )
+    anti = PodAntiAffinity()
+    if rng.random() < 0.7:
+        anti.required_during_scheduling_ignored_during_execution = [
+            PodAffinityTerm(label_selector=own("anti"), topology_key=str(
+                rng.choice([ZONE, "kubernetes.io/hostname"])))
+        ]
+    if rng.random() < 0.7:
+        anti.preferred_during_scheduling_ignored_during_execution = [
+            WeightedPodAffinityTerm(weight=10, pod_affinity_term=PodAffinityTerm(
+                label_selector=own("soft_anti"), topology_key=ZONE))
+        ]
+    p.spec.affinity = Affinity(node_affinity=node_affinity, pod_anti_affinity=anti)
+    spread = []
+    for when, kind in (("DoNotSchedule", "spread"), ("ScheduleAnyway", "soft_spread")):
+        if rng.random() < 0.7:
+            spread.append(TopologySpreadConstraint(
+                max_skew=int(rng.integers(1, 3)), topology_key=ZONE,
+                when_unsatisfiable=when,
+                label_selector={"matchLabels": {"app": app, "tier": pick(kind) or ""}},
+            ))
+    p.spec.topology_spread_constraints = spread
+    return p
+
+
+class TestLiveCounts:
+    """_needs_compaction reads live-distinct counts kept at write time;
+    the O(live) scans they replace are the oracle here."""
+
+    @staticmethod
+    def _assert_counts_match_scan(cache):
+        ids, labels = _scan_live(cache)
+        assert [dict(c) for c in cache._live_ids] == ids
+        assert cache._live_labels == labels
+        assert cache._needs_compaction() == _scan_needs_compaction(cache)
+
+    @pytest.mark.parametrize("seed,hot", list(enumerate((
+        "shape", "affinity", "preferred", "spread", "anti", "soft_spread",
+        "soft_anti", "labels", "selector",
+    ), start=2**31)))
+    def test_counts_equal_the_scan_under_churn(self, seed, hot):
+        """Seeded upserts, in-place re-upserts that change shapes and
+        labels, binds, removes and compactions: every count equals the
+        scan, and the compaction answer equals the scan's, at each step.
+        The `hot` registry churns fastest, so it is the one that trips."""
+        rng = np.random.default_rng(seed)
+        store = Store()
+        cache = PendingPodCache(store, capacity=16)
+        registry = {
+            "shape": "_shapes", "labels": "_label_sets", "selector": "_labels",
+        }.get(hot, f"_{hot}_shapes")
+        live, answers, peak = [], set(), 0
+        for step in range(2500):
+            draw = rng.random()
+            # creates thin out as the live set grows: it stays near 20,
+            # so churned-away ids soon outnumber live ones four to one
+            if draw < 0.7 - len(live) / 50 or not live:
+                name = f"p{step}"
+                store.create(constrained_pod(name, rng, step, hot))
+                live.append(name)
+            elif draw < 0.6:
+                name = live[int(rng.integers(len(live)))]
+                store.update(constrained_pod(name, rng, step, hot))
+            elif draw < 0.9:
+                name = live.pop(int(rng.integers(len(live))))
+                store.delete("Pod", "default", name)
+            else:
+                name = live.pop(int(rng.integers(len(live))))
+                bound = constrained_pod(name, rng, step, hot)
+                bound.spec.node_name = "n0"
+                store.update(bound)
+            with cache._lock:
+                self._assert_counts_match_scan(cache)
+                answers.add(cache._needs_compaction())
+                peak = max(peak, len(getattr(cache, registry)))
+            if step % 25 == 0:
+                before = cache.compactions
+                cache.snapshot()  # compacts where the answer is True
+                with cache._lock:
+                    self._assert_counts_match_scan(cache)
+                    assert not cache._needs_compaction() or (
+                        cache.compactions == before
+                    )
+        assert len(cache) == len(live)
+        # the hot registry crossed the floor: both answers were checked
+        assert peak >= _COMPACT_FLOOR
+        assert answers == {True, False}
+        assert cache.compactions > 0
+
+    def test_many_live_workloads_check_without_a_scan(self):
+        """257+ live label sets and spread shapes, all live: the check
+        answers False without iterating the live slots."""
+        from karpenter_tpu.api.core import TopologySpreadConstraint
+
+        class NoScan(dict):
+            def values(self):
+                raise AssertionError("the compaction check scanned live slots")
+
+            items = keys = __iter__ = values
+
+        store = Store()
+        cache = PendingPodCache(store)
+        for i in range(300):
+            p = pod(f"web-{i}")
+            p.metadata.labels = {"app": f"web-{i}"}
+            p.spec.topology_spread_constraints = [TopologySpreadConstraint(
+                topology_key=ZONE, label_selector={"matchLabels": {"app": f"web-{i}"}})]
+            store.create(p)
+        assert len(cache._label_sets) >= 257 and len(cache._spread_shapes) >= 257
+        with cache._lock:
+            assert _scan_needs_compaction(cache) is False
+        cache._slot, cache._sparse = NoScan(cache._slot), NoScan(cache._sparse)
+        assert cache._needs_compaction() is False
+        snap = cache.snapshot()
+        assert len(snap.spread_shapes) == 301 and cache.compactions == 0
 
 
 class TestReservationsCache:

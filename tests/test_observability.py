@@ -1199,6 +1199,60 @@ class TestConstrainedEncodeSpans:
         assert feed.encode_memo is not None  # the constrained encode ran
 
 
+class TestPendingSnapshotSpan:
+    """Each MP batch reads the pending-pod arena inside one
+    `pendingcapacity.snapshot` span, whose `compacted` arg says whether
+    that read compacted the arena; every compaction adds one to the
+    `pending_cache.compact` counter (snapshot_ms.constrained reads the
+    span)."""
+
+    def test_one_span_per_batch_and_compactions_counted(self, fresh_tracer):
+        store, pod = _fleet_store(constrained=True)
+        feed = TestConstrainedEncodeSpans._feed(store)
+        solve = TestConstrainedEncodeSpans._solve
+
+        def snapshot_spans():
+            spans = fresh_tracer.snapshot()
+            root = next(s for s in spans if s["name"] == "reconcile.tick")
+            reads = [s for s in spans if s["name"] == "pendingcapacity.snapshot"]
+            assert len(reads) == 1 and reads[0]["parent"] == root["id"]
+            return reads[0], root["args"].get("counters", {})
+
+        solve(fresh_tracer, store, feed)
+        read, counters = snapshot_spans()
+        assert read["args"]["compacted"] is False
+        assert "pending_cache.compact" not in counters
+
+        # a peak that drains leaves the arena mostly dead: the next read
+        # compacts it, once
+        for i in range(300):
+            store.create(pod(f"burst-{i}", {"app": "burst"}))
+        for i in range(300):
+            store.delete("Pod", "default", f"burst-{i}")
+        fresh_tracer.clear()
+        solve(fresh_tracer, store, feed)
+        read, counters = snapshot_spans()
+        assert read["args"]["compacted"] is True
+        assert counters["pending_cache.compact"]["count"] == 1
+        assert 0 < counters["pending_cache.compact"]["seconds"] * 1e6 <= read["dur_us"]
+
+        fresh_tracer.clear()
+        solve(fresh_tracer, store, feed)
+        read, counters = snapshot_spans()
+        assert read["args"]["compacted"] is False
+        assert "pending_cache.compact" not in counters
+
+    def test_oracle_path_opens_no_snapshot_span(self, fresh_tracer):
+        from karpenter_tpu.metrics.producers.pendingcapacity import solve_pending
+
+        store, _ = _fleet_store(constrained=True)
+        with fresh_tracer.trace("reconcile.tick"):
+            solve_pending(store, [], GaugeRegistry())
+        names = {s["name"] for s in fresh_tracer.snapshot()}
+        assert "pendingcapacity.snapshot" not in names
+        assert "pendingcapacity.census" in names
+
+
 # -- the end-to-end simulate pin ---------------------------------------------
 
 
