@@ -191,12 +191,93 @@ def build_binpack(pods, groups, seed, affinity=0.0, anti=0.0):
 # -- phase: the runtime tick at cluster scale --------------------------------
 
 
+def _e2e_anti_affinity(app: str):
+    """Required hostname self-anti-affinity: the StatefulSet
+    one-replica-per-node pattern, through the REAL spec
+    parse -> columnar anti-shape intern -> _expand_anti_rows ->
+    pod_exclusive operand path."""
+    from karpenter_tpu.api.core import (
+        Affinity,
+        LabelSelector,
+        PodAffinityTerm,
+        PodAntiAffinity,
+    )
+
+    return Affinity(
+        pod_anti_affinity=PodAntiAffinity(
+            required_during_scheduling_ignored_during_execution=[
+                PodAffinityTerm(
+                    label_selector=LabelSelector(
+                        match_labels={"app": app}
+                    ),
+                    topology_key="kubernetes.io/hostname",
+                )
+            ]
+        )
+    )
+
+
+def _e2e_affinity_shapes():
+    """A few realistic node-affinity variants: require ssd, forbid hdd,
+    prefer ssd (weight 80)."""
+    from karpenter_tpu.api.core import (
+        Affinity,
+        NodeAffinity,
+        NodeSelector,
+        NodeSelectorRequirement,
+        NodeSelectorTerm,
+        PreferredSchedulingTerm,
+    )
+
+    def required(operator, values):
+        return Affinity(
+            node_affinity=NodeAffinity(
+                required_during_scheduling_ignored_during_execution=(
+                    NodeSelector(
+                        node_selector_terms=[
+                            NodeSelectorTerm(
+                                match_expressions=[
+                                    NodeSelectorRequirement(
+                                        key="disk",
+                                        operator=operator,
+                                        values=values,
+                                    )
+                                ]
+                            )
+                        ]
+                    )
+                )
+            )
+        )
+
+    prefer_ssd = Affinity(
+        node_affinity=NodeAffinity(
+            preferred_during_scheduling_ignored_during_execution=[
+                PreferredSchedulingTerm(
+                    weight=80,
+                    preference=NodeSelectorTerm(
+                        match_expressions=[
+                            NodeSelectorRequirement(
+                                key="disk", operator="In", values=["ssd"]
+                            )
+                        ]
+                    ),
+                )
+            ]
+        )
+    )
+    return [
+        required("In", ["ssd"]),
+        required("NotIn", ["hdd"]),
+        prefer_ssd,
+    ]
+
+
 def _populate(store, pods: int, groups: int, rng):
-    """bench.py:run_e2e's fleet: `pods` pending pods (with some node
-    affinity, hostname anti-affinity and zone spread), one node per
-    group, and a pendingCapacity MetricsProducer per group. Returns the
-    pod factory for churn."""
-    from bench import _e2e_affinity_shapes, _e2e_anti_affinity
+    """The smoke fleet: `pods` pending pods (with some node affinity,
+    hostname anti-affinity and zone spread), one node per group, and a
+    pendingCapacity MetricsProducer per group. Returns the pod factory
+    for churn."""
     from karpenter_tpu.api.core import (
         Affinity,
         Container,

@@ -30,7 +30,6 @@ CHECK_ROOTS = (
     "karpenter_tpu",
     "tests",
     "hack",  # the gate checks itself
-    "bench.py",
     "__graft_entry__.py",
 )
 

@@ -387,15 +387,6 @@ def parse_args(argv=None) -> argparse.Namespace:
         "'8x1' (default: pods-major factorization of the device count)",
     )
     parser.add_argument(
-        "--no-resident",
-        action="store_true",
-        help="disable the device-resident fleet state (docs/"
-        "solver-service.md 'Device-resident fleet state'): every solve "
-        "dispatch re-uploads its full operand stack instead of serving "
-        "resident buffers with scatter updates; outputs are "
-        "bit-identical either way",
-    )
-    parser.add_argument(
         "--consolidate",
         action="store_true",
         help="enable the consolidation engine (batched node-drain "
@@ -850,7 +841,6 @@ def main(argv=None) -> int:
             solver_shard_threshold=args.shard_threshold,
             solver_shard_devices=args.shard_devices,
             solver_shard_mesh=_parse_mesh_shape(args.shard_mesh),
-            solver_resident=not args.no_resident,
             forecast_history=args.forecast_history,
             stale_metric_max_age_s=args.stale_metric_max_age,
             cost_default_hourly=args.cost_default_hourly,

@@ -52,8 +52,8 @@ Posture (the tracing/provenance precedent): DEFAULT OFF behind
 `--introspect`. Disabled, the hot path pays one attribute read per
 compile miss and nothing else — decisions are property-pinned
 byte-identical and the ledger stays mark-free
-(tests/test_introspect.py). `make bench-introspect` publishes the
-honest <=2% tick-overhead number.
+(tests/test_introspect.py). Its enabled tick overhead is not measured
+on the chip (no benchmark cell turns it on).
 """
 
 from __future__ import annotations

@@ -35,9 +35,9 @@ tenancy scatter. In-thread code reaches the current batch through
 Posture matches tracing: DEFAULT OFF (`--provenance` enables). A
 disabled ledger costs one attribute read per site and records nothing —
 decisions are byte-identical with the ledger on or off (the ledger only
-observes; tests/test_provenance.py property-pins both), and `make
-bench-provenance` publishes the enabled-vs-disabled tick overhead
-(<=5% target, docs/BENCHMARKS.md).
+observes; tests/test_provenance.py property-pins both). Its enabled
+tick overhead is not measured on the chip (no benchmark cell turns it
+on).
 """
 
 from __future__ import annotations
@@ -276,8 +276,8 @@ class DecisionLedger:
         if n == 1:
             # the common single-HA tick: per-item writes skip the
             # slice-assignment broadcast machinery (~4x cheaper per
-            # column, and the bench-provenance <=5% budget is paid in
-            # exactly this shape)
+            # column, and the ledger's tick overhead is paid in exactly
+            # this shape)
             return self._commit_single(cols, now)
         with self._lock:
             keep = min(n, self.capacity)

@@ -46,9 +46,9 @@ module is the correlation layer:
 Overhead posture: the span ring is a bounded deque; a disabled tracer
 (`enabled = False`) returns a shared no-op context manager and None
 handles — the hot path pays one attribute read and the recording
-check. `make bench-trace` publishes the enabled-vs-disabled tick
-overhead (<5% target, docs/BENCHMARKS.md); tests/test_observability.py
-pins a regression ceiling.
+check. The benchmark's cells run with the tracer on, so its cost is
+inside their numbers (PERF.md); tests/test_observability.py pins a
+regression ceiling.
 """
 
 from __future__ import annotations

@@ -57,13 +57,6 @@ class Options:
     solver_shard_threshold: int = 1 << 24
     solver_shard_devices: Optional[int] = None
     solver_shard_mesh: Optional[tuple] = None
-    # device-resident fleet state (docs/solver-service.md
-    # "Device-resident fleet state"): singleton solve dispatches keep
-    # their operand stack resident on device and churn applies as
-    # batched scatter updates — bit-identical to the re-upload path,
-    # so ON by default; False pins the upload-every-dispatch posture
-    # (the bench-resident OFF arm and an operator escape hatch).
-    solver_resident: bool = True
     # degradation-ladder tuning (docs/resilience.md):
     # engine requeue backoff under retryable failures — first retry in
     # ~[base, 3*base], monotone up to the cap
@@ -185,9 +178,9 @@ class Options:
     # "Compile pre-warm"): compile the smallest bucket rungs of the
     # always-on kernel families (solve + decide) before the first
     # event arrives, so a cold plane's first event pass doesn't eat a
-    # first-touch jit compile (hotpath BASELINE: idle p99 533 ms vs
-    # p50 30 ms). Skipped per rung when the compile cache already
-    # hits; the persistent compile cache turns the
+    # first-touch jit compile (a cold service's idle-queue p99 is its
+    # compiles, many times its p50). Skipped per rung when the compile
+    # cache already hits; the persistent compile cache turns the
     # remaining cost into a disk read.
     prewarm_compile: bool = False
     # fused steady-state tick (ops/fusedtick.py, docs/solver-service.md
@@ -297,7 +290,6 @@ class KarpenterRuntime:
             shard_threshold=options.solver_shard_threshold,
             shard_devices=options.solver_shard_devices,
             shard_mesh_shape=options.solver_shard_mesh,
-            resident=options.solver_resident,
         )
         # the solver introspection plane (observability/devicetelemetry
         # .py): ALWAYS built — a disabled plane is one attribute read

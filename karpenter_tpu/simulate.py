@@ -2808,9 +2808,7 @@ def multitenant_fleet_inputs(
     amplitude differ per tenant (tenant fleets are NOT in phase — the
     fairness and batching machinery must handle skewed demand), with
     the previous tick's desired fed back as spec/status replicas.
-    Deterministic in (tenant, tick, seed); shared with `bench.py
-    --multitenant` so the bench times exactly the matrices the
-    simulator steps."""
+    Deterministic in (tenant, tick, seed)."""
     import math as _math
 
     from karpenter_tpu.ops import decision as D

@@ -162,9 +162,9 @@ class ResidentFleetState:
         # memory is a REAL accelerator behind a transfer link (TPU/GPU
         # — the backends with donation support). On CPU the "device"
         # memory IS host memory, so a copy-on-write scatter costs about
-        # what the memcpy upload it avoids costs (measured ~0.94x by
-        # `make bench-resident`) and auto mode serves identity hits +
-        # rebuilds instead. "always"/"never" force it (tests, bench).
+        # what the memcpy upload it avoids costs, and auto mode serves
+        # identity hits + rebuilds instead. "always"/"never" force it
+        # (tests).
         self.scatter = scatter
         self._scatter_auto: Optional[bool] = None
         # drop generation: drop_all bumps it, and a store whose serve

@@ -466,7 +466,6 @@ class SolverService:
         shard_threshold: int = DEFAULT_SHARD_THRESHOLD,
         shard_devices: Optional[int] = None,
         shard_mesh_shape: Optional[tuple] = None,
-        resident: bool = True,
     ):
         if on_timeout not in ("fallback", "raise"):
             raise ValueError(f"on_timeout must be fallback|raise, got {on_timeout!r}")
@@ -530,9 +529,7 @@ class SolverService:
         self._shard_broken = False
         # device-resident fleet state (solver/resident.py): singleton
         # solve dispatches keep their operand stack on device and churn
-        # applies as batched scatters. `resident=False` pins the
-        # upload-every-dispatch path (the bench-resident OFF arm).
-        self.resident_enabled = resident
+        # applies as batched scatters.
         from karpenter_tpu.solver.resident import ResidentFleetState
 
         self._resident = ResidentFleetState()
@@ -851,8 +848,8 @@ class SolverService:
         families before the first real request arrives
         (docs/solver-service.md "Compile pre-warm").
 
-        Why: the hotpath BASELINE shows service_idle_p99_ms 533 ms vs
-        p50 30 ms — the tail is first-touch jit compiles, which would
+        Why: a cold service's idle-queue p99 is many times its p50 —
+        the tail is first-touch jit compiles, which would
         otherwise eat the entire sub-second budget on a cold plane's
         first EVENT PASS (the latency the event-driven reconcile loop
         exists to remove). The warm-up drives one tiny REAL dispatch per
@@ -935,7 +932,8 @@ class SolverService:
 
     def stage_percentiles(self) -> Dict[str, Dict[str, float]]:
         """{stage: {"p50_ms", "p99_ms", "n"}} over the retained latency
-        rings — the per-stage breakdown bench.py --hotpath publishes."""
+        rings — the per-stage breakdown (pad, upload, dispatch,
+        scatter, ...) the benchmark's solve cell reads."""
         with self._stage_lock:
             snapshot = {k: list(v) for k, v in self._stages.items()}
         out = {}
@@ -2982,19 +2980,18 @@ class SolverService:
     ):
         """The device-resident serve path for a SINGLETON map-strategy
         group: returns the resident stacked operands (batch axis 1), or
-        None when residency does not apply — disabled, a coalesced
+        None when residency does not apply — a coalesced
         multi-request batch (those stacks are ephemeral by nature), the
         vmap consolidate family, or an out-of-process device solver.
 
         kind accounting: a "hit" records a 0.0 upload sample (nothing
-        crossed the link — the claim `make bench-hotpath`'s upload p50
-        verifies), a "scatter" records the scatter wall time under the
+        crossed the link, so a steady fleet's upload p50 falls to ~0),
+        a "scatter" records the scatter wall time under the
         resident_scatter stage + gauge (its host->device traffic is the
         changed-row blocks inside the jitted scatter), and a "rebuild"
         billed its full upload through the normal _upload hook."""
         if (
-            not self.resident_enabled
-            or strategy != "map"
+            strategy != "map"
             or len(live) != 1
             or self.device_solver is not None
         ):

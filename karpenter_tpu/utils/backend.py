@@ -1,7 +1,7 @@
 """Backend selection and the persistent compile cache, for entry points.
 
-Three rules, shared by `python -m karpenter_tpu`, the sidecar, bench.py
-and chip_smoke.py:
+Three rules, shared by `python -m karpenter_tpu`, the sidecar and
+chip_smoke.py:
 
 - The CPU runs only when asked for by name (`JAX_PLATFORMS=cpu`, as the
   tests and the split-mode control plane do). A process that asked for

@@ -6,6 +6,8 @@ Three tiers (SURVEY.md §4 "solver correctness needs a new tier"):
 - semantics: taints/tolerations, nodeSelector, resource fit, assignment rules
 """
 
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -353,8 +355,6 @@ class TestWeightedDedup:
             pod_intolerant=intol_base, pod_required=required_base,
             group_taints=taints, group_labels=labels,
         )
-        import dataclasses
-
         dedup = dataclasses.replace(
             dedup, pod_weight=jnp.asarray(counts.astype(np.int32))
         )
@@ -397,15 +397,83 @@ class TestWeightedDedup:
         assert out.nodes_needed.tolist() == [1]
 
 
+def build_inputs(pods: int, types: int, taints: int, labels: int, seed: int):
+    """Random pods and instance types: resource requests, taints and
+    required labels at fixed densities."""
+    rng = np.random.default_rng(seed)
+    # pod requests: cpu in cores, memory in GiB, 1 pod slot
+    req = np.stack(
+        [
+            rng.uniform(0.05, 8.0, pods),
+            rng.uniform(0.1, 32.0, pods),
+            np.ones(pods),
+        ],
+        axis=1,
+    ).astype(np.float32)
+    # instance types: cpu 2..128 cores, proportional memory, 110 pod slots
+    cpu = rng.choice([2, 4, 8, 16, 32, 64, 96, 128], types).astype(np.float32)
+    mem = cpu * rng.choice([2.0, 4.0, 8.0], types).astype(np.float32)
+    alloc = np.stack([cpu, mem, np.full(types, 110.0, np.float32)], axis=1)
+    intol = rng.random((pods, taints)) < 0.05
+    group_taints = rng.random((types, taints)) < 0.1
+    required = rng.random((pods, labels)) < 0.03
+    group_labels = rng.random((types, labels)) < 0.8
+    return B.BinPackInputs(
+        pod_requests=jnp.asarray(req),
+        pod_valid=jnp.ones((pods,), bool),
+        pod_intolerant=jnp.asarray(intol),
+        pod_required=jnp.asarray(required),
+        group_allocatable=jnp.asarray(alloc),
+        group_taints=jnp.asarray(group_taints),
+        group_labels=jnp.asarray(group_labels),
+    )
+
+
+def build_multicluster_inputs(
+    pods: int, clusters: int, types_per_cluster: int,
+    taints: int, labels: int, seed: int, flex_fraction: float = 0.3,
+):
+    """BASELINE.json config 5: spot-interruption re-pack across clusters.
+
+    K clusters each contribute types_per_cluster node groups carrying a
+    cluster-identity label (first K slots of the label universe). Pods are
+    spot-interruption refugees: 70% must stay in their home cluster
+    (required cluster label — the nodeSelector a real multi-cluster
+    scheduler would stamp), 30% are flexible and may re-pack anywhere.
+    Same solver, same encoding — the cluster boundary IS a label
+    constraint, so multi-cluster costs nothing extra on device.
+    """
+    types = clusters * types_per_cluster
+    base = build_inputs(pods, types, taints, labels, seed)
+    rng = np.random.default_rng(seed + 1)
+
+    group_labels = np.asarray(base.group_labels).copy()
+    group_labels[:, :clusters] = False
+    for c in range(clusters):
+        group_labels[
+            c * types_per_cluster : (c + 1) * types_per_cluster, c
+        ] = True
+
+    pod_required = np.asarray(base.pod_required).copy()
+    pod_required[:, :clusters] = False
+    home = rng.integers(0, clusters, pods)
+    pinned = rng.random(pods) >= flex_fraction
+    pod_required[np.arange(pods)[pinned], home[pinned]] = True
+
+    return dataclasses.replace(
+        base,
+        group_labels=jnp.asarray(group_labels),
+        pod_required=jnp.asarray(pod_required),
+    )
+
+
 class TestMultiClusterRepack:
     def test_pinned_pods_stay_home_flexible_cross(self):
-        """BASELINE config 5 (bench.py --clusters): the cluster boundary
-        is a required-label constraint — pinned pods must land on their
-        home cluster's groups; flexible pods may re-pack anywhere."""
-        import bench
-
+        """BASELINE config 5: the cluster boundary is a required-label
+        constraint — pinned pods must land on their home cluster's
+        groups; flexible pods may re-pack anywhere."""
         pods, clusters, tpc = 600, 4, 5
-        inputs = bench.build_multicluster_inputs(
+        inputs = build_multicluster_inputs(
             pods, clusters, tpc, taints=8, labels=12, seed=3
         )
         out = B.binpack(inputs, buckets=16)

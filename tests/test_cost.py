@@ -13,8 +13,7 @@ The load-bearing pins:
     ScalableNodeGroup controller door;
   * karpenter_cost_* / karpenter_warmpool_* passing the promtool-style
     exposition lint;
-  * the non-slow batched-vs-per-HA regression guard (`make bench-cost`
-    publishes the full numbers).
+  * the non-slow batched-vs-per-HA regression guard.
 """
 
 from __future__ import annotations
@@ -690,9 +689,9 @@ class TestApiValidation:
 
 class TestRegressionGuard:
     def test_batched_refine_beats_per_ha_loop(self):
-        """Non-slow guard for the bench-cost claim: one fleet dispatch
-        must beat N single-row dispatches (generously — the published
-        numbers live in docs/BENCHMARKS.md)."""
+        """Non-slow guard for the batched refine: one fleet dispatch
+        must beat N single-row dispatches (generously; the refine on its
+        own is not measured on the chip)."""
         import jax
 
         inputs = random_inputs(0, n=64, m=3)

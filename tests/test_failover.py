@@ -623,33 +623,3 @@ class TestDebugReplicasEndpoint:
         finally:
             server.stop()
             plane.close()
-
-
-def _baseline():
-    path = os.path.join(REPO_ROOT, "BASELINE.json")
-    with open(path) as f:
-        return json.load(f)
-
-
-class TestFailoverRegressionGuard:
-    def test_published_blackout_bounded(self):
-        """Published bench-failover rows keep the handoff blackout
-        within 3 lease durations with exactly-once actuation."""
-        published = _baseline().get("published", {})
-        records = {
-            k: v for k, v in published.items() if " failover (" in k
-        }
-        if not records:
-            pytest.skip(
-                "no failover record in BASELINE.json — run "
-                "`make bench-failover`"
-            )
-        for key, rec in records.items():
-            assert rec["converged"], key
-            assert rec["duplicate_actuations"] == 0, key
-            assert rec["lost_actuations"] == 0, key
-            assert rec["stale_write_rejected"], key
-            assert rec["blackout_p99_s"] <= 3 * rec["lease_duration_s"], (
-                f"{key}: handoff blackout regressed to "
-                f"{rec['blackout_p99_s']}s"
-            )

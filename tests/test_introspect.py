@@ -21,8 +21,7 @@ telemetry & introspection"):
     per-entry resident-LRU byte accounting, retires evicted entries'
     series, and feeds the self-SLO monitor as its fourth source;
   * /debug/solver reports the full posture in one JSON document;
-  * overhead stays bounded (the structural guard; `make
-    bench-introspect` publishes the honest <=2% number).
+  * overhead stays bounded (the structural guard).
 """
 
 import json
@@ -739,9 +738,9 @@ class TestDebugSolverEndpoint:
 
 class TestIntrospectOverheadGuard:
     def test_enabled_vs_disabled_tick_overhead(self, fresh_recorder):
-        """The wall-clock guard with generous flake headroom: `make
-        bench-introspect` publishes the honest <=2% number
-        (docs/BENCHMARKS.md); this pin catches gross regressions."""
+        """The wall-clock guard with generous flake headroom (the
+        overhead is not measured on the chip); this pin catches gross
+        regressions."""
         import time
 
         runtime, _provider, tick = _churn_world()

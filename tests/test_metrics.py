@@ -409,7 +409,7 @@ class TestMetricsDocDrift:
 
 class TestHistogramPercentile:
     """HistogramVec.percentile — the estimator behind the simulator
-    report's and bench-journal's provisioning-lead p50/p99 columns —
+    report's provisioning-lead p50/p99 columns —
     must apply Prometheus's histogram_quantile() semantics: linear
     interpolation within the bucket holding the rank, clamp-to-bound
     beyond the last finite bucket, None for an empty series."""

@@ -20,8 +20,7 @@ The acceptance pins (ISSUE 9 / docs/observability.md):
     solver.request stays a direct child of reconcile.MetricsProducer;
   * the single-chip Pallas solve records pad, upload, dispatch and
     scatter in the request's trace;
-  * tracing-enabled tick overhead stays bounded (the structural guard;
-    `make bench-trace` publishes the honest <5% number).
+  * tracing-enabled tick overhead stays bounded (the structural guard).
 """
 
 import json
@@ -1369,9 +1368,10 @@ class TestTracingOverheadGuard:
         assert per_tick <= 20, f"{per_tick:.1f} spans/tick"
 
     def test_enabled_vs_disabled_tick_overhead(self, fresh_tracer):
-        """The wall-clock guard, with generous flake headroom: `make
-        bench-trace` publishes the honest <5% number (docs/BENCHMARKS.md);
-        this pin catches gross regressions (>75% on sub-ms ticks)."""
+        """The wall-clock guard, with generous flake headroom (the
+        benchmark's cells run with the tracer on, so its cost is inside
+        their numbers); this pin catches gross regressions (>75% on
+        sub-ms ticks)."""
         off = self._tick_p50(enabled=False)
         on = self._tick_p50(enabled=True)
         assert on <= off * 1.75 + 0.002, (

@@ -12,8 +12,7 @@ provenance"):
     stays 0) — the same property the tracing-off pin established;
   * the ring is columnar and bounded: batch appends, oldest-drop,
     filtered queries, crash-safe JSONL export;
-  * overhead stays bounded (the structural guard; `make
-    bench-provenance` publishes the honest <=5% number).
+  * overhead stays bounded (the structural guard).
 """
 
 import json
@@ -455,9 +454,9 @@ class TestMultitenantProvenanceAcceptance:
 
 class TestProvenanceOverheadGuard:
     def test_enabled_vs_disabled_tick_overhead(self, fresh_ledger):
-        """The wall-clock guard with generous flake headroom: `make
-        bench-provenance` publishes the honest <=5% number
-        (docs/BENCHMARKS.md); this pin catches gross regressions."""
+        """The wall-clock guard with generous flake headroom (the
+        overhead is not measured on the chip); this pin catches gross
+        regressions."""
         import time
 
         import numpy as _np

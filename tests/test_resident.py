@@ -8,8 +8,8 @@ into a PendingPodCache, delta encoding through a SnapshotDeltaCache
 SolverService whose residency layer consumes them. Every tick asserts
 three ways:
 
-  * the service's outputs equal a resident-OFF service's outputs on the
-    SAME inputs (device path) and the numpy mirror's outputs (integer
+  * the service's outputs equal the plain XLA program's outputs on the
+    cold inputs (no service in between) and the numpy mirror's (integer
     fields exact, lp_bound within the established ±1 contract — though
     on the same backend it is in fact equal);
   * the RESIDENT DEVICE BUFFERS equal pad_to_bucket(cold full encode)
@@ -39,6 +39,7 @@ from karpenter_tpu.metrics.producers.pendingcapacity.encoder import (
     resident_plan,
 )
 from karpenter_tpu.metrics.registry import GaugeRegistry
+from karpenter_tpu.ops.binpack import solve as binpack_solve
 from karpenter_tpu.ops.numpy_binpack import binpack_numpy
 from karpenter_tpu.solver import SolverService
 from karpenter_tpu.solver.bucketing import pad_to_bucket
@@ -74,8 +75,8 @@ def make_profiles():
 
 
 class ResidentWorld:
-    """One tenant's real encode->solve pipeline with residency ON, plus
-    a residency-OFF reference service for output parity."""
+    """One tenant's real encode->solve pipeline through the resident
+    service; outputs are checked against the plain XLA program."""
 
     def __init__(self, shard_threshold=0):
         self.cache = PendingPodCache(store=None, capacity=64)
@@ -88,13 +89,9 @@ class ResidentWorld:
         # "devices" (scatter ~= upload there), but these tests PIN the
         # scatter math itself and run on the virtual-CPU harness
         self.svc._resident.scatter = "always"
-        self.ref = SolverService(
-            registry=GaugeRegistry(), shard_threshold=0, resident=False,
-        )
 
     def close(self):
         self.svc.close()
-        self.ref.close()
 
     def upsert(self, p):
         self.cache._upsert((p.metadata.namespace, p.metadata.name), p)
@@ -114,7 +111,7 @@ class ResidentWorld:
         )
         out = self.svc.solve(inputs, buckets=BUCKETS, backend="xla")
         cold = _encode_full(snap, self.profiles)
-        ref = self.ref.solve(cold, buckets=BUCKETS, backend="xla")
+        ref = binpack_solve(cold, buckets=BUCKETS, backend="xla")
         ref_np = binpack_numpy(cold, buckets=BUCKETS)
         for mirror, label in ((ref, "xla"), (ref_np, "numpy")):
             np.testing.assert_array_equal(
@@ -262,8 +259,6 @@ class TestResidentChurn:
         # identity chain occupies its own resident entry
         b.svc.close()
         b.svc = a.svc
-        b.ref.close()
-        b.ref = a.ref
         try:
             for i in range(8):
                 a.upsert(pod(f"a{i}", cpu=f"{100 + i}m"))
@@ -350,7 +345,7 @@ class TestNeverBlock:
 
 
 class TestUnchangedTickSkipsEncodeAndUpload:
-    """The bench-resident regression guard (non-slow): an unchanged
+    """The residency regression guard (non-slow): an unchanged
     fleet tick costs zero encode and zero upload."""
 
     def test_unchanged_tick_zero_encode_zero_upload(self, world):

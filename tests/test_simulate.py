@@ -422,8 +422,7 @@ class TestSimulateEventloop:
     pod-arrival replay must show tick-paced e2e p99 at interval scale
     (multi-second), event-driven e2e p99 SUB-SECOND on the same
     karpenter_reconcile_e2e_seconds histogram, the same fleet fixed
-    point in both arms, and churn-storm solve amplification <= 2x —
-    the `make bench-eventloop` contract at a fast scale."""
+    point in both arms, and churn-storm solve amplification <= 2x."""
 
     CONFIG = dict(ticks=12, arrivals=10, storm_events=200, seed=7)
 

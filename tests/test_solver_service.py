@@ -592,7 +592,7 @@ class TestPipelinedDispatch:
 
     def test_closed_loop_concurrency_overlaps_dispatches(self):
         """The PR 8 satellite pin: CLOSED-LOOP concurrent callers (the
-        bench-hotpath shape that used to publish pipeline_overlaps: 0)
+        shape that used to record pipeline_overlaps: 0)
         must record >= 1 overlap. The fix: a lone coalesced batch with
         nothing in flight splits into pipeline chunks, so chunk k+1's
         dispatch overlaps chunk k's compute — and every result stays
@@ -775,8 +775,8 @@ class TestLatencyRegressionGuard:
     def test_idle_service_p50_within_2x_of_direct(self):
         """The coalescing tax must not return: on an idle queue the
         service path stays within 2x of a direct ops/binpack call on a
-        small fixed workload (the non-slow canary for the bench-hotpath
-        acceptance ratio)."""
+        small fixed workload (the non-slow canary for the idle-queue
+        path)."""
         inputs = make_inputs(256, 8, seed=42)
         iters = 15
 
