@@ -448,16 +448,17 @@ def _priority_tier_operands(snap, profiles, row_idx, n_pods, n_groups):
     return pod_priority, group_tier
 
 
-def _expand(snap, profiles, row_idx, row_weight, group_label_dicts, census):
+def _expand(snap, profiles, row_idx, row_weight, group_label_dicts, census,
+            stats=None):
     """(row_idx, row_weight, forbidden-or-None, exclusive-or-None) after
-    the two row expansions."""
+    the two row expansions; `stats` as _expand_spread_rows takes it."""
     # hard topology spread: constrained rows split into balanced
     # per-domain sub-rows (same source row gathered more than once, each
     # chunk masked to its domain's groups) — the device program is
     # unchanged, spread rides the existing forbidden-mask operand
     row_idx, row_weight, spread_forbidden = _expand_spread_rows(
         snap, profiles, row_idx, row_weight, group_label_dicts,
-        census=census,
+        census=census, stats=stats,
     )
     # required self pod-(anti-)affinity: hostname rows flag the
     # pod_exclusive operand, domain keys cap one replica per domain
@@ -475,7 +476,8 @@ def _live(ids, row_idx) -> bool:
 def _expand_traced(snap, profiles, row_idx, row_weight, group_label_dicts,
                    census):
     """_expand inside a `pendingcapacity.expand` span (args rows_in,
-    rows_out, exclusive_rows) where a spread or anti shape is live, its
+    rows_out, exclusive_rows, and batched_rows: the spread rows filled
+    by the batched water-fill) where a spread or anti shape is live, its
     census queries timed into the tracer's `census.query` counter.
     Where none is live, or the tracer is off, nothing is opened and the
     clock is not read."""
@@ -489,15 +491,17 @@ def _expand_traced(snap, profiles, row_idx, row_weight, group_label_dicts,
             snap, profiles, row_idx, row_weight, group_label_dicts, census
         )
     timed = None if census is None else TimedCensus(census, _clock)
+    stats = {"batched_rows": 0}
     with tracer.span("pendingcapacity.expand", rows_in=len(row_idx)) as span:
         out = _expand(
             snap, profiles, row_idx, row_weight, group_label_dicts,
-            census if timed is None else timed,
+            census if timed is None else timed, stats,
         )
         span.args["rows_out"] = len(out[0])
         span.args["exclusive_rows"] = (
             0 if out[3] is None else int(out[3].sum())
         )
+        span.args["batched_rows"] = stats["batched_rows"]
     if timed is not None:
         tracer.add("census.query", timed.seconds)
     return out

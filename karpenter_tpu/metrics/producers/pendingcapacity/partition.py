@@ -14,45 +14,57 @@ def _water_fill(counts, caps, schedulable: int, seed: int) -> np.ndarray:
     (None = unbounded). Returns per-domain additions. The remainder at
     the final water level rotates by content-keyed `seed`, so no domain
     is systematically overweighted across shapes (and the choice never
-    depends on arena-local numbering). All-numpy: runs per dedup row on
-    the churned-tick hot path."""
+    depends on arena-local numbering). The one-row case of
+    _water_fill_rows, which holds the rule."""
+    return _water_fill_rows(
+        np.asarray(counts, np.int64)[None],
+        None if caps is None else np.asarray(caps, np.int64)[None],
+        [schedulable],
+        [seed],
+    )[0]
+
+
+def _water_fill_rows(counts, caps, schedulable, seed) -> np.ndarray:
+    """_water_fill for R rows over one domain count at once: `counts`
+    and `caps` are [R, D] (caps None = every row unbounded),
+    `schedulable` and `seed` length R. Each row's binary search on its
+    water level runs the same steps as a search of that row alone, in
+    lockstep with the others, so every row's additions are exactly the
+    one-row answer. Returns [R, D] int64 additions."""
     c = np.asarray(counts, np.int64)
     cap = None if caps is None else np.asarray(caps, np.int64)
+    s = np.asarray(schedulable, np.int64)
 
-    def filled(level: int) -> int:
-        add = np.clip(level - c, 0, None)
-        if cap is not None:
-            add = np.minimum(add, cap)
-        return int(add.sum())
+    def clipped(level):
+        add = np.clip(level[:, None] - c, 0, None)
+        return add if cap is None else np.minimum(add, cap)
 
-    lo = int(c.min())
-    hi = (
-        int(c.max()) + schedulable
-        if cap is None
-        else int((c + cap).max())
-    )
-    hi = max(lo, hi)
-    while lo < hi:  # greatest level with filled(level) <= schedulable
+    lo = c.min(axis=1)
+    hi = c.max(axis=1) + s if cap is None else (c + cap).max(axis=1)
+    hi = np.maximum(lo, hi)
+    # greatest level with filled(level) <= schedulable; a row already
+    # settled (lo == hi) keeps its level, since mid == lo there
+    while (lo < hi).any():
         mid = (lo + hi + 1) // 2
-        if filled(mid) <= schedulable:
-            lo = mid
-        else:
-            hi = mid - 1
-    level = lo
-    out = np.clip(level - c, 0, None)
-    if cap is not None:
-        out = np.minimum(out, cap)
-    remainder = schedulable - int(out.sum())
-    if remainder:
-        at_level = c + out == level
-        can_grow = at_level if cap is None else at_level & (out < cap)
-        candidates = np.flatnonzero(can_grow)
-        if len(candidates):
-            offset = seed % len(candidates)
-            chosen = (
-                np.arange(len(candidates)) - offset
-            ) % len(candidates) < remainder
-            out[candidates[chosen]] += 1
+        fits = clipped(mid).sum(axis=1) <= s
+        lo = np.where(fits, mid, lo)
+        hi = np.where(fits, hi, mid - 1)
+    out = clipped(lo)
+    remainder = s - out.sum(axis=1)
+    if remainder.any():
+        # the remainder goes to the domains at the level that can still
+        # grow, in domain order from position seed % candidates on
+        can_grow = c + out == lo[:, None]
+        if cap is not None:
+            can_grow &= out < cap
+        n = can_grow.sum(axis=1)
+        offset = np.asarray(seed, np.int64) % np.maximum(n, 1)
+        position = np.cumsum(can_grow, axis=1) - 1
+        chosen = can_grow & (
+            (position - offset[:, None]) % np.maximum(n, 1)[:, None]
+            < remainder[:, None]
+        )
+        out += chosen
     return out
 
 

@@ -11,7 +11,12 @@ import numpy as np
 
 from .census import _entry_census, _row_node_filter
 from .exclusion import _anti_frozen_mask, _canonical_row_key
-from .partition import _UNBOUNDED, _partition_chunks, _water_fill
+from .partition import (
+    _UNBOUNDED,
+    _partition_chunks,
+    _water_fill,
+    _water_fill_rows,
+)
 
 def _entry_caps(
     skew, min_domains, self_match, values, counts_e, present_e
@@ -326,7 +331,8 @@ def _spread_partition_view(shape, row_filter, label_dicts, census,
 
 
 def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each guard is a documented spread rule
-    snap, profiles, row_idx, row_weight, label_dicts_fn, census=None
+    snap, profiles, row_idx, row_weight, label_dicts_fn, census=None,
+    stats=None,
 ):
     """Topology spread (DoNotSchedule, non-hostname keys): partition each
     constrained row's weight into per-domain sub-rows, WATER-FILLED
@@ -362,6 +368,12 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
     remaining capacities; rows of one workload consume a SHARED budget
     in canonical content order; without a census (hand-built snapshot
     paths) counts are zero and the splits are plain balanced.
+
+    A workload's only row whose view has no partition entries and no
+    dead groups (every zone-spread Deployment of a single-key fleet)
+    cannot shed or carry a ledger over: its fill waits for one
+    _water_fill_rows pass over every such row of its domain count, and
+    `stats["batched_rows"]` (when a dict is given) counts those rows.
 
     Returns (row_idx, row_weight, spread_forbidden[rows, T]-or-None);
     unconstrained snapshots pass through untouched.
@@ -456,23 +468,22 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
             )
         ),
     )
-    out_idx, out_weight, out_forbidden = [], [], []
+    # per row in `order`, its (row, weight, forbidden) pieces; a batched
+    # row's list is filled after the walk, by its domain count's batch
+    batches: Dict[int, list] = {}
+    emitted = []
     for i in order:
         sid = live_ids[i]
         entry = plan.get(int(sid))
         if entry is None:
-            out_idx.append(row_idx[i])
-            out_weight.append(row_weight[i])
-            out_forbidden.append(no_forbidden)
+            emitted.append([(row_idx[i], row_weight[i], no_forbidden)])
             continue
         namespace, entries, values, masks, eligible, domains = entry
         weight = int(row_weight[i])
         if not values or weight == 0:
             # no group exposes the key(s): unschedulable by spread —
             # keep the row, forbid everything, so the pods are COUNTED
-            out_idx.append(row_idx[i])
-            out_weight.append(row_weight[i])
-            out_forbidden.append(all_forbidden)
+            emitted.append([(row_idx[i], row_weight[i], all_forbidden)])
             continue
         d = len(values)
         row_filter = (
@@ -517,6 +528,26 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
                 label_dicts, eligible, domains, extra_dead=anti_dead,
             )
             view_memo[view_key] = view
+        # content-keyed remainder rotation (see _water_fill)
+        seed = weight + int(
+            np.ascontiguousarray(snap.requests[row_idx[i]])
+            .view(np.uint8)
+            .sum()
+        )
+        pieces = []
+        emitted.append(pieces)
+        if (
+            sid_rows[int(sid)] == 1
+            and not view["others"]
+            and view["dead"] is None  # an anti_dead mask seeds it
+        ):
+            # the workload's ledger would start fresh and never be read
+            # again, and no partition entry can shed: the fill is the
+            # plain water-fill of the view's counts under its caps
+            batches.setdefault(d, []).append(
+                (row_idx[i], weight, seed, view, masks, pieces)
+            )
+            continue
         ledger = ledgers.get(int(sid))
         if ledger is None:
             ledger = {
@@ -535,16 +566,10 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
             weight,
         )
         schedulable = min(weight, int(caps.sum()))
-        # content-keyed remainder rotation (see _water_fill)
-        seed = weight + int(
-            np.ascontiguousarray(snap.requests[row_idx[i]])
-            .view(np.uint8)
-            .sum()
-        )
         additions = _water_fill(
             ledger["counts"], caps, schedulable, seed
         )
-        pieces = _partition_chunks(
+        chunks = _partition_chunks(
             additions, masks, view, ledger["others_placed"], n_groups,
             seed,
         )
@@ -554,29 +579,24 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
         # also accumulate into the fill-order counts, exactly like the
         # scheduler's sequential skew accounting
         kept = np.zeros(d, np.int64)
-        for rank, count, _extra in pieces:
+        for rank, count, _extra in chunks:
             kept[rank] += count
         ledger["placed"] = ledger["placed"] + kept
         if view["first_selfmatch"]:
             ledger["counts"] = ledger["counts"] + kept
-        dead = view["dead"]
-        placed = 0
-        for rank, count, extra in pieces:
-            placed += count
-            forbidden = masks[rank]
-            if dead is not None or extra is not None:
-                forbidden = forbidden.copy()
-                if dead is not None:
-                    forbidden |= dead
-                if extra is not None:
-                    forbidden |= extra
-            out_idx.append(row_idx[i])
-            out_weight.append(np.int32(count))
+        pieces += _row_pieces(
+            row_idx[i], weight, chunks, masks, view["dead"], all_forbidden
+        )
+    for batch in batches.values():
+        _fill_batch(batch, all_forbidden)
+    if stats is not None:
+        stats["batched_rows"] = sum(len(b) for b in batches.values())
+    out_idx, out_weight, out_forbidden = [], [], []
+    for pieces in emitted:
+        for row, weight, forbidden in pieces:
+            out_idx.append(row)
+            out_weight.append(weight)
             out_forbidden.append(forbidden)
-        if placed < weight:
-            out_idx.append(row_idx[i])
-            out_weight.append(np.int32(weight - placed))
-            out_forbidden.append(all_forbidden)
     return (
         np.asarray(out_idx, np.intp),
         np.asarray(out_weight, np.int32),
@@ -584,3 +604,57 @@ def _expand_spread_rows(  # lint: allow-complexity — per-domain chunking: each
     )
 
 
+def _fill_batch(batch, all_forbidden):
+    """Fill one domain count's batched rows — each (row, weight, seed,
+    view, [D, T] masks, pieces list) — by one _water_fill_rows pass, and
+    emit each row's pieces into its list. The caps are the per-row
+    rule's with nothing placed yet: min(static, budget) clipped at 0,
+    then at the row's weight."""
+    weights = np.array([b[1] for b in batch], np.int64)
+    caps = np.minimum(
+        np.clip(
+            np.minimum(
+                np.stack([b[3]["static"] for b in batch]),
+                np.stack([b[3]["budget"] for b in batch]),
+            ),
+            0,
+            None,
+        ),
+        weights[:, None],
+    )
+    additions = _water_fill_rows(
+        np.stack([b[3]["counts"] for b in batch]),
+        caps,
+        np.minimum(weights, caps.sum(axis=1)),
+        [b[2] for b in batch],
+    )
+    for (row, weight, _seed, _view, masks, pieces), adds in zip(
+        batch, additions.tolist()
+    ):
+        # what _partition_chunks hands back for a view with no entries
+        chunks = [(rank, count, None) for rank, count in enumerate(adds)
+                  if count]
+        pieces += _row_pieces(row, weight, chunks, masks, None,
+                              all_forbidden)
+
+
+def _row_pieces(row, weight, chunks, masks, dead, all_forbidden):
+    """A spread row's emitted (row, weight, forbidden) pieces: one per
+    kept chunk, masked to its domain (plus the view's dead groups and
+    the chunk's extra mask, copying only then), and the all-forbidden
+    remainder that no domain could hold."""
+    pieces = []
+    placed = 0
+    for rank, count, extra in chunks:
+        placed += count
+        forbidden = masks[rank]
+        if dead is not None or extra is not None:
+            forbidden = forbidden.copy()
+            if dead is not None:
+                forbidden |= dead
+            if extra is not None:
+                forbidden |= extra
+        pieces.append((row, np.int32(count), forbidden))
+    if placed < weight:
+        pieces.append((row, np.int32(weight - placed), all_forbidden))
+    return pieces

@@ -1154,6 +1154,7 @@ class TestConstrainedEncodeSpans:
         assert expand["args"]["rows_in"] == 2
         assert expand["args"]["rows_out"] > 2
         assert expand["args"]["exclusive_rows"] == 1
+        assert expand["args"]["batched_rows"] == 1
         query = root["args"]["counters"]["census.query"]
         assert query["count"] == 1
         assert 0 < query["seconds"] * 1e6 <= expand["dur_us"]
@@ -1174,6 +1175,21 @@ class TestConstrainedEncodeSpans:
         spans = {s["name"]: s for s in fresh_tracer.snapshot()}
         assert spans["pendingcapacity.census"]["args"]["refresh"] is True
         assert "pendingcapacity.expand" in spans
+
+    @pytest.mark.parametrize("web_rows, batched", [(1, 1), (2, 0)])
+    def test_expand_counts_batched_spread_rows(self, fresh_tracer, web_rows, batched):
+        """batched_rows counts the spread rows filled by the one batched
+        water-fill: a workload's only row, never a workload split over
+        several rows (here by a second, group-pinned web row)."""
+        store, pod = _fleet_store(constrained=True)
+        if web_rows == 2:
+            web = store.get("Pod", "default", "web-0")
+            store.create(pod("web-pinned", {"app": "web"}, node_selector={"group": "b"},
+                             topology_spread_constraints=web.spec.topology_spread_constraints))
+        self._solve(fresh_tracer, store, self._feed(store))
+        expand = next(s for s in fresh_tracer.snapshot() if s["name"] == "pendingcapacity.expand")
+        assert expand["args"]["rows_in"] == 1 + web_rows
+        assert expand["args"]["batched_rows"] == batched
 
     def test_unconstrained_encode_opens_neither(self, fresh_tracer):
         store, _ = _fleet_store(constrained=False)
