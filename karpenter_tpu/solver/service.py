@@ -148,6 +148,10 @@ RESIDENT_REBUILDS = "resident_rebuilds_total"
 # the one-chip Mosaic path's pad into reused host staging areas
 # {name=reuse|alloc} (docs/solver-service.md "Shape-bucket ladder")
 PAD_STAGING = "pad_staging_total"
+# bytes of the optional per-pod operands the one-chip Mosaic pad hands
+# to the upload {name=forbidden|score|exclusive} (docs/solver-service.md
+# "Shape-bucket ladder")
+OPERAND_BYTES = "solve_operand_bytes_total"
 # boot-time compile pre-warm (docs/solver-service.md "Compile pre-warm")
 PREWARM_COMPILES = "prewarm_compiles_total"
 PREWARM_MS = "prewarm_ms"
@@ -338,6 +342,9 @@ class SolverStatistics:
     # the one-chip Mosaic path's pads into host staging areas
     pad_staging_reuses: int = 0  # pads into an area an earlier call made
     pad_staging_allocs: int = 0  # pads that had to make a new area
+    # padded bytes of the optional per-pod operands the Mosaic pad
+    # handed to the upload, by operand (_COUNTED_OPERANDS)
+    operand_bytes: Dict[str, int] = field(default_factory=dict)
     pipeline_splits: int = 0  # lone batches chunked so the pipeline overlaps
     # backend health FSM + watchdog (docs/resilience.md)
     device_failures: int = 0  # total device-path failures (any rung)
@@ -619,6 +626,7 @@ class SolverService:
             SUBSYSTEM, RESIDENT_REBUILDS, kind="counter"
         )
         self._c_pad_staging = reg(SUBSYSTEM, PAD_STAGING, kind="counter")
+        self._c_operand_bytes = reg(SUBSYSTEM, OPERAND_BYTES, kind="counter")
         # boot-time pre-warm: rungs compiled {name=<family>} and the
         # wall cost of each family's warm dispatch — near-zero when the
         # persistent compile cache (JAX_COMPILATION_CACHE_DIR) served it
@@ -2822,6 +2830,7 @@ class SolverService:
                 if span is not None:
                     span.args["staged"] = staged
                 padded, kernel = B.fold_for_pallas(staged_inputs)
+                self._count_operand_bytes(padded, span)
             t0 = _time.perf_counter()
             with self._dispatch_span("solver.dispatch", [request]):
                 with self._device_section([request], grace=grace):
@@ -2880,6 +2889,26 @@ class SolverService:
         areas[key] = area
         self._c_pad_staging.inc(staged, "-")
         return pad_to_bucket(inputs, shape, out=area), staged
+
+    def _count_operand_bytes(self, padded: BinPackInputs, span) -> None:
+        """Count the optional per-pod operands of one request's padded,
+        folded set bound for the one-chip Mosaic upload: their bytes
+        from `.nbytes`, never a scan, into stats.operand_bytes and the
+        counter by operand, and their sum into the pad span's
+        `operand_bytes` arg (0 for a bare solve)."""
+        total = 0
+        for name, operand in _COUNTED_OPERANDS:
+            array = getattr(padded, operand)
+            if array is None:
+                continue
+            size = int(array.nbytes)
+            total += size
+            self.stats.operand_bytes[name] = (
+                self.stats.operand_bytes.get(name, 0) + size
+            )
+            self._c_operand_bytes.inc(name, "-", size)
+        if span is not None:
+            span.args["operand_bytes"] = total
 
     def _begin_batched_xla(
         self, shape, buckets: int, live: List[_Request],
@@ -3315,6 +3344,15 @@ class SolverService:
         from karpenter_tpu.ops.numpy_binpack import binpack_numpy
 
         return binpack_numpy(inputs, buckets=buckets)
+
+
+# the optional per-pod operands whose padded bytes the Mosaic pad
+# counts (SolverService._count_operand_bytes), by counted name
+_COUNTED_OPERANDS = (
+    ("forbidden", "pod_group_forbidden"),
+    ("score", "pod_group_score"),
+    ("exclusive", "pod_exclusive"),
+)
 
 
 def _first_span(live: List[_Request]):

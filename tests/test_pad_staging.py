@@ -193,6 +193,37 @@ class TestServicePath:
         ]
         assert staged == ["alloc", "reuse"]
 
+    def test_operand_bytes_count_the_padded_operands(self, fresh_tracer):
+        """stats.operand_bytes, solve_operand_bytes_total and the pad
+        span's operand_bytes count the padded mask, score and exclusive
+        flags of each call, and stay at 0 for a bare solve."""
+        service = _pallas_service()
+        with_operands = make_inputs(300, 20, seed=1, optional=True)
+        with_operands = dataclasses.replace(
+            with_operands,
+            pod_group_forbidden=np.random.default_rng(1).random((300, 20)) < 0.3,
+        )
+        try:
+            service.submit(make_inputs(300, 20), buckets=8).result()
+            assert service.stats.operand_bytes == {}
+            for _ in range(2):
+                service.submit(with_operands, buckets=8).result()
+        finally:
+            service.close()
+        pods, groups = bucket_shape(with_operands)[:2]
+        assert (pods, groups) == (384, 24)
+        per_call = {"forbidden": pods * groups, "score": 4 * pods * groups,
+                    "exclusive": pods}
+        assert service.stats.operand_bytes == {k: 2 * v for k, v in per_call.items()}
+        counter = service.registry.gauge("solver", "solve_operand_bytes_total")
+        for name, size in per_call.items():
+            assert counter.get(name, "-") == 2 * size
+        spans = [
+            s["args"].get("operand_bytes") for s in fresh_tracer.snapshot()
+            if s["name"] == "solver.pad"
+        ]
+        assert spans == [0, sum(per_call.values()), sum(per_call.values())]
+
     def test_areas_are_bounded_least_recent_first_out(self):
         from karpenter_tpu.solver.service import _STAGING_AREAS
 

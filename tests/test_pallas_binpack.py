@@ -194,6 +194,59 @@ class TestPallasMatchesXLA:
             np.asarray(xla.nodes_needed) > np.asarray(free.nodes_needed)
         ).any()
 
+    @pytest.mark.parametrize("seed", range(3))
+    def test_all_operands_through_the_service_match_the_reference(self, seed):
+        """forbidden, score and exclusive together, on weighted rows at a
+        shape the service pads (203 x 37 to its 256 x 48 bucket), through
+        SolverService.submit on the Mosaic path, equal the benchmark's
+        plain reference (benchmark/reference/binpack.py); each operand
+        moves the answer."""
+        import dataclasses
+
+        from benchmark.reference import binpack as ref
+        from karpenter_tpu.metrics.registry import GaugeRegistry
+        from karpenter_tpu.solver import SolverService
+
+        rng = np.random.default_rng(400 + seed)
+        pods, types = 203, 37
+        base = random_inputs(rng, pods, types)
+        operands = dict(
+            forbidden=rng.random((pods, types)) < 0.3,
+            score=(rng.integers(0, 3, (pods, types)) * 40).astype(np.float32),
+            exclusive=rng.random(pods) < 0.2,
+        )
+        weight = rng.integers(1, 50, pods).astype(np.int32)
+        inputs = dataclasses.replace(
+            base,
+            pod_group_forbidden=operands["forbidden"],
+            pod_group_score=operands["score"],
+            pod_exclusive=operands["exclusive"],
+            pod_weight=weight,
+        )
+        service = SolverService(registry=GaugeRegistry(), backend="pallas")
+        try:
+            got = service.submit(inputs, buckets=16).result()
+            assert service.stats.served == {"solve/pallas": 1}
+            assert service.stats.constraint_reroutes == 0
+        finally:
+            service.close()
+        plain = dict(
+            requests=np.asarray(base.pod_requests),
+            valid=np.asarray(base.pod_valid),
+            intolerant=np.asarray(base.pod_intolerant),
+            required=np.asarray(base.pod_required),
+            alloc=np.asarray(base.group_allocatable),
+            taints=np.asarray(base.group_taints),
+            labels=np.asarray(base.group_labels),
+            weight=weight,
+            buckets=16,
+        )
+        diff = ref.compare(got, ref.binpack(**plain, **operands))
+        assert diff["mismatches"] == 0 and diff["lp_off"] <= 1, diff
+        for name in operands:
+            without = {k: v for k, v in operands.items() if k != name}
+            assert ref.compare(got, ref.binpack(**plain, **without))["mismatches"] > 0, name
+
     def test_semantics_taints_and_labels(self):
         # group 0 tainted (pod 0 intolerant); group 1 lacks pod 1's label
         inputs = make_inputs(
